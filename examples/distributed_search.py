@@ -40,14 +40,12 @@ def main():
     lay = ChunkLayout("aisaq", d, "float32", R, m)
     print("building 4 per-shard Vamana sub-indices ...")
     shards = build_sharded(base, 4, R=R, L=32, seed=0)
-    arrays = stack_shards(shards, cents, codes, lay)
-
     mesh = make_test_mesh((2, 4), ("data", "model"))
+    arrays = stack_shards(shards, cents, codes, lay, mesh)
     search = jax.jit(sharded_search_fn(
         mesh, k=10, L=48, w=4, max_hops=64, layout=lay, metric="l2",
         backend="ref"))
-    ash, qsh = input_sharding(mesh)
-    arrays = jax.tree.map(jax.device_put, arrays, ash)
+    _, qsh = input_sharding(mesh)
     qdev = jax.device_put(jnp.asarray(queries), qsh)
 
     ids, dists = search(arrays, qdev)          # compile

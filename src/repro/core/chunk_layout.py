@@ -12,9 +12,13 @@ Two physical disciplines (DESIGN.md §2):
   * file layout — 4 KiB LBA blocks; a chunk never straddles a block boundary
     unless chunk > block, in which case it starts block-aligned and uses
     ceil(chunk/B) blocks (paper Fig. 1a/1b).
-  * device layout — one (N, stride) uint8 HBM array with stride padded to a
-    multiple of 128 bytes (dense lane-aligned DMA per chunk row) and every
-    field 4-byte aligned so bitcasts are free.
+  * device layout — one (N, rows, 128) int32 HBM table: each chunk row is
+    `rows` lane-dense 128-word tiles (512 B each), so one row is one
+    tile-legal (1, rows, 128) block DMA. `rows` is 1, 2, 4 or a multiple of
+    8, which is what the TPU's HBM tiling stores without padding. Every
+    field is 4-byte aligned so bitcasts are free, and the neighbor codes
+    start on a multiple of their own width (pq_m bytes) so one neighbor's
+    codes never straddle a 128-word tile row.
 """
 from __future__ import annotations
 
@@ -97,12 +101,17 @@ class ChunkLayout:
 
     # ---- device (HBM) placement -----------------------------------------
     @property
+    def device_rows(self) -> int:
+        """128-word tile rows per chunk row in the (N, rows, 128) table."""
+        end = self.dev_off_pq + self.R * self.pq_m if self.mode == "aisaq" \
+            else self.dev_off_ids + self.R * B_NUM
+        rows = -(-end // 512)
+        return 1 << (rows - 1).bit_length() if rows <= 4 else _align(rows, 8)
+
+    @property
     def device_stride(self) -> int:
-        """Chunk stride in the (N, stride) uint8 HBM array: 128-B aligned."""
-        # keep ids 4-B aligned: b_full is already 4-aligned for f32; for uint8
-        # vectors pad the vector field up to 4.
-        return _align(self.padded_vec_bytes + B_NUM * (1 + self.R)
-                      + (self.R * self.pq_m if self.mode == "aisaq" else 0), 128)
+        """Chunk stride in bytes of the HBM table (a multiple of 512)."""
+        return self.device_rows * 512
 
     @property
     def padded_vec_bytes(self) -> int:
@@ -118,7 +127,8 @@ class ChunkLayout:
 
     @property
     def dev_off_pq(self) -> int:
-        return self.dev_off_ids + self.R * B_NUM
+        end_ids = self.dev_off_ids + self.R * B_NUM
+        return _align(end_ids, self.pq_m) if self.pq_m % 4 == 0 else end_ids
 
     # ---- summary ----------------------------------------------------------
     def describe(self) -> dict:
@@ -179,7 +189,8 @@ def pack_chunks_file(vectors: np.ndarray, adjacency: np.ndarray,
 
 def pack_chunks_device(vectors: np.ndarray, adjacency: np.ndarray,
                        codes: np.ndarray, layout: ChunkLayout) -> np.ndarray:
-    """(N, device_stride) uint8 array — the HBM-resident 'storage' tier."""
+    """(N, device_rows, 128) int32 — the HBM-resident 'storage' tier's
+    shape and bytes."""
     n = vectors.shape[0]
     out = np.zeros((n, layout.device_stride), dtype=np.uint8)
     vb = _vec_bytes(vectors, layout)
@@ -195,7 +206,7 @@ def pack_chunks_device(vectors: np.ndarray, adjacency: np.ndarray,
         nc = np.where((adj >= 0)[:, :, None], codes[safe], 0).astype(np.uint8)
         out[:, layout.dev_off_pq:layout.dev_off_pq + layout.R * layout.pq_m] = \
             nc.reshape(n, -1)
-    return out
+    return out.view(np.int32).reshape(n, layout.device_rows, 128)
 
 
 # ---------------------------------------------------------------------------

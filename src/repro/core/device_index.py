@@ -1,8 +1,9 @@
 """Device (TPU-target) AiSAQ index: HBM chunk table + while_loop beam search.
 
-The HBM-resident `(N, stride/4)` int32 chunk table is the "storage tier"
+The HBM-resident `(N, rows, 128)` int32 chunk table is the "storage tier"
 (DESIGN.md §2). Per-hop work — chunk gather, parse, inline-PQ ADC — is
-`kernels.ops.fused_hop` (Pallas on TPU, jnp ref elsewhere). Nothing
+`kernels.ops.hop` (Pallas on TPU, jnp ref elsewhere) over the operands
+`kernels.ops.hop_inputs` stages once per search. Nothing
 N-proportional is ever needed in VMEM: the only per-query fast-tier state is
 the (L,) candidate list, the (m, ks) LUT and the re-rank pool — the paper's
 `(R + n_ep)·b_pq` residency invariant, tier-shifted.
@@ -25,9 +26,13 @@ from repro.core.chunk_layout import ChunkLayout, chunk_matrix, \
     pack_chunks_device
 from repro.kernels import ops
 
+# rows packed on the host per transfer: bounds a slab at 0.5 GB at sift1m
+# widths (1 GB at kilt-e5's) while the device table fills in place
+_SLAB_ROWS = 1 << 16
+
 
 class DeviceIndex(NamedTuple):
-    chunk_words: jax.Array        # (N, stride/4) int32 — HBM storage tier
+    chunk_words: jax.Array        # (N, rows, 128) int32 — HBM storage tier
     centroids: jax.Array          # (m, ks, dsub) f32
     ep_ids: jax.Array             # (n_ep,) int32
     ep_codes: jax.Array           # (n_ep, m) int32
@@ -47,6 +52,27 @@ class DeviceIndex(NamedTuple):
         return int(resident + per_q * n_queries)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_slab(table: jax.Array, slab: jax.Array, start) -> jax.Array:
+    return jax.lax.dynamic_update_slice_in_dim(table, slab, start, axis=0)
+
+
+def device_table(vectors: np.ndarray, graph: np.ndarray, codes: np.ndarray,
+                 layout: ChunkLayout, device=None) -> jax.Array:
+    """Pack the (N, rows, 128) chunk table onto `device` slab by slab, so
+    neither host nor device ever holds a second full-size copy."""
+    n = vectors.shape[0]
+    if n <= _SLAB_ROWS:
+        return jax.device_put(
+            pack_chunks_device(vectors, graph, codes, layout), device)
+    table = jnp.zeros((n, layout.device_rows, 128), jnp.int32, device=device)
+    for s in range(0, n, _SLAB_ROWS):
+        e = min(n, s + _SLAB_ROWS)
+        slab = pack_chunks_device(vectors[s:e], graph[s:e], codes, layout)
+        table = _put_slab(table, jax.device_put(slab, device), s)
+    return table
+
+
 def from_arrays(vectors: np.ndarray, graph: np.ndarray, centroids: np.ndarray,
                 codes: np.ndarray, *, mode: str = "aisaq",
                 block_bytes: int = 4096) -> Tuple[DeviceIndex, ChunkLayout]:
@@ -55,13 +81,11 @@ def from_arrays(vectors: np.ndarray, graph: np.ndarray, centroids: np.ndarray,
         mode=mode, dim=d,
         data_dtype="uint8" if vectors.dtype == np.uint8 else "float32",
         R=graph.shape[1], pq_m=codes.shape[1], block_bytes=block_bytes)
-    dev = pack_chunks_device(vectors, graph, codes, layout)
-    words = np.ascontiguousarray(dev).view(np.int32).reshape(n, -1)
     mean = vectors.astype(np.float32).mean(axis=0)
     dd = ((vectors.astype(np.float32) - mean) ** 2).sum(axis=1)
     ep = np.argsort(dd)[:1].astype(np.int32)
     idx = DeviceIndex(
-        chunk_words=jnp.asarray(words),
+        chunk_words=device_table(vectors, graph, codes, layout),
         centroids=jnp.asarray(centroids, jnp.float32),
         ep_ids=jnp.asarray(ep),
         ep_codes=jnp.asarray(codes[ep].astype(np.int32)),
@@ -145,6 +169,9 @@ def beam_search_device(index: DeviceIndex, queries: jax.Array, *, k: int,
     R = layout.R
     lut = ops.build_lut(queries, index.centroids, metric=metric,
                         backend=backend)
+    if layout.mode == "aisaq":
+        hop_operands = ops.hop_inputs(lut, queries, layout=layout,
+                                      backend=backend, adc_dtype=adc_dtype)
     n_ep = index.ep_ids.shape[0]
     ep_ids = jnp.broadcast_to(index.ep_ids[None, :], (nq, n_ep))
     ep_d = jax.vmap(lambda l: jnp.sum(
@@ -185,9 +212,9 @@ def beam_search_device(index: DeviceIndex, queries: jax.Array, *, k: int,
         cand_exp = cand_exp.at[qi, pos].max(fvalid)
         # 2. expand: chunk gather + parse + exact dist + neighbor ADC
         if layout.mode == "aisaq":
-            exact, nids, nd = ops.fused_hop(
-                index.chunk_words, fids, lut, queries, layout=layout,
-                metric=metric, backend=backend, adc_dtype=adc_dtype)
+            exact, nids, nd = ops.hop(
+                index.chunk_words, fids, hop_operands, layout=layout,
+                metric=metric, backend=backend)
         else:
             # DiskANN-on-device: ids from chunks, codes from the resident
             # (N, m) table — the memory-hungry baseline placement.

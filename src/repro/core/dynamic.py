@@ -592,18 +592,24 @@ class DynamicHostIndex(HostIndex):
                predicate: Optional[Callable[[int], bool]] = None):
         # the read lock pairs with _write_node's write lock: no torn chunk
         with self._rw.read():
-            ids, stats = super().search(q, k, L, w)
+            ids, stats = self._search_locked(q, k, L, w)
             drop = self.tombstones
             ok = [i for i in ids if int(i) >= 0 and int(i) not in drop
                   and (predicate is None or predicate(int(i)))]
             if len(ok) < k and (drop or predicate is not None):
                 # widen once: tombstones/filters thin the pool
-                ids2, s2 = super().search(q, k * 4, max(L, 2 * k * 4), w)
+                ids2, s2 = self._search_locked(q, k * 4, max(L, 2 * k * 4), w)
                 stats.ios += s2.ios
                 stats.bytes_read += s2.bytes_read
                 ok = [i for i in ids2 if int(i) >= 0 and int(i) not in drop
                       and (predicate is None or predicate(int(i)))]
             return np.asarray(ok[:k], np.int64), stats
+
+    def _search_locked(self, q, k, L, w):
+        # HostIndex.search goes through self.search_batch, whose second read
+        # acquire would queue behind a waiting writer: a deadlock
+        ids, stats = super().search_batch(q[None], k, L, w)
+        return ids[0], stats[0]
 
     def search_batch(self, Q, k, L, w=4, **kw):
         with self._rw.read():

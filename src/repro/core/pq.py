@@ -56,10 +56,13 @@ def split_subspaces(x: jax.Array, m: int) -> jax.Array:
 
 
 def _pairwise_sqdist(x: jax.Array, c: jax.Array) -> jax.Array:
-    """(n, dsub) x (ks, dsub) -> (n, ks) squared L2 (matmul form for MXU)."""
+    """(n, dsub) x (ks, dsub) -> (n, ks) squared L2 (matmul form for MXU).
+
+    HIGHEST precision: the TPU default rounds f32 matmul operands to bf16."""
     xn = jnp.sum(x * x, axis=-1, keepdims=True)          # (n, 1)
     cn = jnp.sum(c * c, axis=-1)                          # (ks,)
-    return xn - 2.0 * (x @ c.T) + cn[None, :]
+    cross = jnp.matmul(x, c.T, precision=jax.lax.Precision.HIGHEST)
+    return xn - 2.0 * cross + cn[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "ks", "iters", "batch"))
@@ -195,7 +198,8 @@ def exact_distances(queries: jax.Array, base: jax.Array, *, metric: str = "l2"
     if metric == "l2":
         return _pairwise_sqdist(queries, base)
     if metric == "mips":
-        return -(queries @ base.T)
+        return -jnp.matmul(queries, base.T,
+                           precision=jax.lax.Precision.HIGHEST)
     raise ValueError(f"unknown metric {metric!r}")
 
 

@@ -33,10 +33,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.chunk_layout import ChunkLayout
-from repro.core.device_index import DeviceIndex, beam_search_device
+from repro.core.device_index import DeviceIndex, beam_search_device, \
+    device_table
 from repro.core.shard_math import (          # noqa: F401  (re-exported)
     ShardAssignment, contiguous_shards, merge_topk)
 
@@ -44,7 +44,7 @@ from repro.core.shard_math import (          # noqa: F401  (re-exported)
 class ShardedIndexArrays(NamedTuple):
     """Stacked per-shard index arrays; leading dim = shard."""
 
-    chunk_words: jax.Array    # (S_h, N_s, W) int32
+    chunk_words: jax.Array    # (S_h, N_s, rows, 128) int32
     centroids: jax.Array      # (m, ks, dsub) f32 — replicated
     ep_ids: jax.Array         # (S_h, n_ep) int32 (shard-local ids)
     ep_codes: jax.Array       # (S_h, n_ep, m) int32
@@ -53,31 +53,48 @@ class ShardedIndexArrays(NamedTuple):
 
 def stack_shards(shards: Sequence[Tuple[int, "np.ndarray", "np.ndarray"]],
                  centroids: np.ndarray, codes_full: np.ndarray,
-                 layout: ChunkLayout) -> ShardedIndexArrays:
-    """shards: list of (global_offset, shard_vectors, shard_graph)."""
-    from repro.core.chunk_layout import pack_chunks_device
-    words, eps, epc, offs = [], [], [], []
+                 layout: ChunkLayout, mesh, *,
+                 shard_axes: Tuple[str, ...] = ("model",)
+                 ) -> ShardedIndexArrays:
+    """shards: list of (global_offset, shard_vectors, shard_graph).
+
+    Returns the arrays placed on `mesh` with shards over `shard_axes`. Each
+    shard's chunk table is packed straight onto the devices that hold it,
+    so no device ever stages another shard's table."""
     n_max = max(v.shape[0] for _, v, _ in shards)
-    for off, vecs, graph in shards:
-        n = vecs.shape[0]
-        codes = codes_full[off:off + n]
-        dev = pack_chunks_device(vecs, graph, codes, layout)
-        w = np.ascontiguousarray(dev).view(np.int32).reshape(n, -1)
-        if n < n_max:  # pad ragged shards with unreachable nodes
-            w = np.pad(w, ((0, n_max - n), (0, 0)))
-        words.append(w)
+    eps, epc, offs = [], [], []
+    for off, vecs, _ in shards:
+        codes = codes_full[off:off + vecs.shape[0]]
         mean = vecs.astype(np.float32).mean(axis=0)
         dd = ((vecs.astype(np.float32) - mean) ** 2).sum(axis=1)
         ep = np.argsort(dd)[:1].astype(np.int32)
         eps.append(ep)
         epc.append(codes[ep].astype(np.int32))
         offs.append(off)
+    sh, _ = input_sharding(mesh, query_axes=(), shard_axes=shard_axes)
+    shape = (len(shards), n_max, layout.device_rows, 128)
+    tables = {}
+    per_device = []
+    for dev, idx in sh.chunk_words.addressable_devices_indices_map(
+            shape).items():
+        s = idx[0].start or 0
+        if s not in tables:
+            off, vecs, graph = shards[s]
+            codes = codes_full[off:off + vecs.shape[0]]
+            pad = n_max - vecs.shape[0]   # ragged shards: unreachable rows
+            vecs = np.pad(vecs, ((0, pad), (0, 0)))
+            graph = np.pad(graph, ((0, pad), (0, 0)), constant_values=-1)
+            tables[s] = (vecs, graph, codes)
+        per_device.append(
+            device_table(*tables[s], layout, device=dev)[None])
     return ShardedIndexArrays(
-        chunk_words=jnp.asarray(np.stack(words)),
-        centroids=jnp.asarray(centroids, jnp.float32),
-        ep_ids=jnp.asarray(np.stack(eps)),
-        ep_codes=jnp.asarray(np.stack(epc)),
-        offsets=jnp.asarray(np.array(offs, np.int32)))
+        chunk_words=jax.make_array_from_single_device_arrays(
+            shape, sh.chunk_words, per_device),
+        centroids=jax.device_put(np.asarray(centroids, np.float32),
+                                 sh.centroids),
+        ep_ids=jax.device_put(np.stack(eps), sh.ep_ids),
+        ep_codes=jax.device_put(np.stack(epc), sh.ep_codes),
+        offsets=jax.device_put(np.array(offs, np.int32), sh.offsets))
 
 
 def sharded_search_fn(mesh, *, k: int, L: int, w: int, max_hops: int,
@@ -96,10 +113,10 @@ def sharded_search_fn(mesh, *, k: int, L: int, w: int, max_hops: int,
     """
     query_axes = _norm_axes(query_axes)
     qspec = P(query_axes, None) if query_axes else P(None, None)
-    sspec = P(shard_axes, None, None)
+    sspec = P(shard_axes, None, None, None)
 
     def local_search(words, cents, ep_ids, ep_codes, offset, queries):
-        # shapes inside shard_map: words (1, N_s, W), queries (B_l, d)
+        # shapes inside shard_map: words (1, N_s, rows, 128), queries (B_l, d)
         idx = DeviceIndex(chunk_words=words[0], centroids=cents,
                           ep_ids=ep_ids[0], ep_codes=ep_codes[0])
 
@@ -128,12 +145,12 @@ def sharded_search_fn(mesh, *, k: int, L: int, w: int, max_hops: int,
         negd, pos = jax.lax.top_k(-all_d, k)
         return jnp.take_along_axis(all_ids, pos, axis=1), -negd
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=(sspec, P(), P(shard_axes, None), P(shard_axes, None, None),
                   P(shard_axes), qspec),
         out_specs=(qspec, qspec),
-        check_rep=False)
+        check_vma=False)
 
     def search(arrays: ShardedIndexArrays, queries: jax.Array):
         return fn(arrays.chunk_words, arrays.centroids, arrays.ep_ids,
@@ -143,8 +160,7 @@ def sharded_search_fn(mesh, *, k: int, L: int, w: int, max_hops: int,
 
 
 def _norm_axes(axes) -> Tuple[str, ...]:
-    """Drop None placeholders: (None,) means 'replicated', which older JAX
-    only accepts as an empty spec (P(None) rather than P((None,)))."""
+    """Drop None placeholders: (None,) means 'replicated' (an empty spec)."""
     return tuple(a for a in (axes or ()) if a is not None)
 
 
@@ -153,7 +169,7 @@ def input_sharding(mesh, query_axes=("data",), shard_axes=("model",)):
     query_axes = _norm_axes(query_axes)
     qspec = P(query_axes, None) if query_axes else P(None, None)
     return ShardedIndexArrays(
-        chunk_words=NamedSharding(mesh, P(shard_axes, None, None)),
+        chunk_words=NamedSharding(mesh, P(shard_axes, None, None, None)),
         centroids=NamedSharding(mesh, P()),
         ep_ids=NamedSharding(mesh, P(shard_axes, None)),
         ep_codes=NamedSharding(mesh, P(shard_axes, None, None)),

@@ -76,7 +76,6 @@ def cp_attention_wrap(flash_fn, seq_len: int):
     ways = _MESH.shape["model"]
     if seq_len % ways or seq_len // ways < 128:
         return None
-    from jax.experimental.shard_map import shard_map
     dp = tuple(a for a in ("pod", "data") if a in _MESH.axis_names)
     s_local = seq_len // ways
 
@@ -84,9 +83,9 @@ def cp_attention_wrap(flash_fn, seq_len: int):
         off = jax.lax.axis_index("model") * s_local
         return flash_fn(q, k, v, off)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=_MESH,
         in_specs=(P(dp, "model", None, None), P(dp, None, None, None),
                   P(dp, None, None, None)),
         out_specs=P(dp, "model", None, None),
-        check_rep=False)
+        check_vma=False)

@@ -56,7 +56,6 @@ def make_compressed_dp_grads(loss_fn, mesh, batch_example,
     dp_axis, grads exchanged via compressed_psum (replacing the implicit
     GSPMD fp32 all-reduce). `batch_example` fixes the batch pytree
     structure for the in_specs."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(params, batch):
@@ -67,8 +66,8 @@ def make_compressed_dp_grads(loss_fn, mesh, batch_example,
         return loss, g
 
     bspecs = jax.tree.map(lambda _: P(dp_axis), batch_example)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), bspecs),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)

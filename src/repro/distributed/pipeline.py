@@ -16,12 +16,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def make_pp_mesh(n_stages: int, n_data: int = 1):
-    from repro.launch.mesh import make_mesh_compat
-    return make_mesh_compat((n_stages, n_data), ("pp", "data"))
+    from repro.launch.mesh import make_test_mesh
+    return make_test_mesh((n_stages, n_data), ("pp", "data"))
 
 
 def pipeline_forward(mesh: Mesh, stage_fn: Callable, n_microbatches: int):
@@ -67,8 +66,8 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, n_microbatches: int):
             jnp.where(stage == S - 1, outs, jnp.zeros_like(outs)), "pp")
         return outs
 
-    fn = shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                    in_specs=(P("pp"), P(None)),
                    out_specs=P(None),
-                   check_rep=False)
+                   check_vma=False)
     return fn

@@ -10,24 +10,20 @@ backend:
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.chunk_layout import ChunkLayout
 from repro.kernels import ref as _ref
-from repro.kernels.chunk_adc import fused_hop as _fused_hop_pallas, \
-    quantize_lut
+from repro.kernels import chunk_adc as _chunk_adc
+from repro.kernels.chunk_adc import quantize_lut
 from repro.kernels.pq_adc import pq_adc as _pq_adc_pallas
 from repro.kernels.pq_lut import pq_lut as _pq_lut_pallas
 from repro.kernels.rerank import rerank as _rerank_pallas
 
 
 def default_backend() -> str:
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env:
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
@@ -55,29 +51,47 @@ def adc(lut: jax.Array, codes: jax.Array, *, backend: str = "auto"
     return _pq_adc_pallas(lut, codes, interpret=(b == "pallas_interpret"))
 
 
-def fused_hop(chunk_words: jax.Array, frontier_ids: jax.Array, lut: jax.Array,
-              queries: jax.Array, *, layout: ChunkLayout, metric: str = "l2",
-              backend: str = "auto", adc_dtype: str = "f32"):
-    """Batched AiSAQ hop. frontier_ids (nq, w) -> see chunk_adc.fused_hop.
+def hop_inputs(lut: jax.Array, queries: jax.Array, *, layout: ChunkLayout,
+               backend: str = "auto", adc_dtype: str = "f32"):
+    """Loop-invariant operands of `hop`, built once per search.
 
     adc_dtype="int8" runs the §Perf adc-int8 path: per-query symmetric LUT
-    quantization, s8xs8->s32 one-hot contraction at 2x MXU rate. The ref
-    backend emulates the identical numerics (quantize + dequantize the LUT)
-    so recall-parity tests run anywhere.
+    quantization. The ref backend emulates the identical numerics (quantize
+    + dequantize the LUT) so recall-parity tests run anywhere.
     """
     assert adc_dtype in ("f32", "int8"), adc_dtype
-    b = _resolve(backend)
-    if b == "ref":
+    if _resolve(backend) == "ref":
         if adc_dtype == "int8":
             lut_q8, scale = quantize_lut(lut)
             lut = lut_q8.astype(jnp.float32) * (scale / 127.0)[:, None, None]
+        return lut, queries
+    return _chunk_adc.hop_inputs(lut, queries, layout=layout,
+                                 quantized=(adc_dtype == "int8"))
+
+
+def hop(chunk_words: jax.Array, frontier_ids: jax.Array, operands, *,
+        layout: ChunkLayout, metric: str = "l2", backend: str = "auto"):
+    """Batched AiSAQ hop over `hop_inputs` operands. frontier_ids (nq, w)
+    -> (exact (nq, w), ids (nq, w, R), nbr_d (nq, w, R))."""
+    b = _resolve(backend)
+    if b == "ref":
+        lut, queries = operands
         fn = functools.partial(_ref.fused_hop_ref, chunk_words,
                                layout=layout, metric=metric)
         return jax.vmap(fn)(frontier_ids, lut, queries)
-    return _fused_hop_pallas(chunk_words, frontier_ids, lut, queries,
-                             layout=layout, metric=metric,
-                             quantized=(adc_dtype == "int8"),
-                             interpret=(b == "pallas_interpret"))
+    return _chunk_adc.hop(chunk_words, frontier_ids, *operands,
+                          layout=layout, metric=metric,
+                          interpret=(b == "pallas_interpret"))
+
+
+def fused_hop(chunk_words: jax.Array, frontier_ids: jax.Array, lut: jax.Array,
+              queries: jax.Array, *, layout: ChunkLayout, metric: str = "l2",
+              backend: str = "auto", adc_dtype: str = "f32"):
+    """`hop_inputs` + `hop` in one call (one hop of one batch)."""
+    operands = hop_inputs(lut, queries, layout=layout, backend=backend,
+                          adc_dtype=adc_dtype)
+    return hop(chunk_words, frontier_ids, operands, layout=layout,
+               metric=metric, backend=backend)
 
 
 def rerank(queries: jax.Array, cand: jax.Array, *, metric: str = "l2",

@@ -3,6 +3,10 @@
 Device-side chunks are handled as int32 *words* (stride/4 per row): 4-byte
 aligned field offsets mean id/float fields are single words and uint8 fields
 unpack with shifts — all TPU-lowerable ops (no sub-word memory ops needed).
+
+Every f32 reduction here is elementwise products summed by ``jnp.sum``, never
+a dot: XLA's default TPU matmul precision rounds f32 operands through bf16,
+which would break agreement with the numpy host oracle.
 """
 from __future__ import annotations
 
@@ -57,16 +61,15 @@ def parse_chunks_words(words: jax.Array, layout: ChunkLayout):
 
 def pq_lut_ref(queries: jax.Array, centroids: jax.Array, *, metric: str
                ) -> jax.Array:
-    """(q, d), (m, ks, dsub) -> (q, m, ks) f32."""
+    """(q, d), (m, ks, dsub) -> (q, m, ks) f32 (the host twin's formula)."""
     q = queries.shape[0]
     m, ks, dsub = centroids.shape
-    qs = queries.astype(jnp.float32).reshape(q, m, dsub)
+    qs = queries.astype(jnp.float32).reshape(q, m, 1, dsub)
+    c = centroids.astype(jnp.float32)[None]
     if metric == "mips":
-        return -jnp.einsum("qmd,mkd->qmk", qs, centroids)
-    qn = jnp.sum(qs * qs, axis=-1)                        # (q, m)
-    cn = jnp.sum(centroids * centroids, axis=-1)          # (m, ks)
-    cross = jnp.einsum("qmd,mkd->qmk", qs, centroids)
-    return qn[:, :, None] - 2.0 * cross + cn[None, :, :]
+        return -jnp.sum(qs * c, axis=-1)
+    diff = c - qs
+    return jnp.sum(diff * diff, axis=-1)
 
 
 def pq_adc_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:
@@ -81,7 +84,7 @@ def fused_hop_ref(chunk_words: jax.Array, frontier_ids: jax.Array,
                   metric: str):
     """One AiSAQ beam-search hop given gathered chunk rows.
 
-    chunk_words: (N, stride/4) int32 full chunk table (the HBM 'storage').
+    chunk_words: (N, rows, 128) int32 full chunk table (the HBM 'storage').
     frontier_ids: (w,) int32 node ids to expand (may contain -1 padding).
     lut: (m, ks) f32 for this query. query: (d,) f32.
 
@@ -90,14 +93,15 @@ def fused_hop_ref(chunk_words: jax.Array, frontier_ids: jax.Array,
     """
     w = frontier_ids.shape[0]
     safe = jnp.clip(frontier_ids, 0, chunk_words.shape[0] - 1)
-    rows = chunk_words[safe]                              # gather (w, S)
+    rows = chunk_words[safe].reshape(w, -1)               # gather (w, S)
     vec, deg, ids, codes = parse_chunks_words(rows, layout)
     fvalid = frontier_ids >= 0
+    q = query.astype(jnp.float32)[None, :]
     if metric == "mips":
-        exact = -(vec @ query.astype(jnp.float32))
+        exact = -jnp.sum(vec * q, axis=-1)
     else:
-        diff = vec - query.astype(jnp.float32)[None, :]
-        exact = jnp.einsum("wd,wd->w", diff, diff)
+        diff = vec - q
+        exact = jnp.sum(diff * diff, axis=-1)
     exact = jnp.where(fvalid, exact, jnp.inf)
     nvalid = (ids >= 0) & fvalid[:, None]
     if layout.mode == "aisaq":
@@ -114,8 +118,8 @@ def fused_hop_ref(chunk_words: jax.Array, frontier_ids: jax.Array,
 def rerank_ref(query: jax.Array, cand: jax.Array, *, metric: str) -> jax.Array:
     """(d,), (c, d) -> (c,) exact distances."""
     cand = cand.astype(jnp.float32)
-    q = query.astype(jnp.float32)
+    q = query.astype(jnp.float32)[None, :]
     if metric == "mips":
-        return -(cand @ q)
-    diff = cand - q[None, :]
-    return jnp.einsum("cd,cd->c", diff, diff)
+        return -jnp.sum(cand * q, axis=-1)
+    diff = cand - q
+    return jnp.sum(diff * diff, axis=-1)

@@ -322,7 +322,6 @@ def _build_ann_cell(arch: ArchConfig, shape: ShapeConfig, mesh: Mesh,
 
     cfg: IndexConfig = arch.model
     layout = layout_for(cfg, "aisaq")
-    W = layout.device_stride // 4
     total_chunk_gb = cfg.n_vectors * layout.device_stride / 1e9
     per_dev_budget = 8.0     # GB of HBM we allow the chunk table per device
     # mode A: index shards over `model` only, queries over dp;
@@ -341,7 +340,7 @@ def _build_ann_cell(arch: ArchConfig, shape: ShapeConfig, mesh: Mesh,
     m, ks = cfg.pq_m, cfg.pq_ks
     dsub = cfg.dim // m
     arrays = ShardedIndexArrays(
-        chunk_words=SDS((n_shards, N_s, W), jnp.int32),
+        chunk_words=SDS((n_shards, N_s, layout.device_rows, 128), jnp.int32),
         centroids=SDS((m, ks, dsub), jnp.float32),
         ep_ids=SDS((n_shards, cfg.n_ep), jnp.int32),
         ep_codes=SDS((n_shards, cfg.n_ep, m), jnp.int32),
@@ -354,7 +353,7 @@ def _build_ann_cell(arch: ArchConfig, shape: ShapeConfig, mesh: Mesh,
         mesh, k=10, L=128, w=cfg.beamwidth, max_hops=cfg.max_hops,
         layout=layout, metric=cfg.metric, backend="ref",
         query_axes=query_axes, shard_axes=shard_axes, query_chunk=qchunk)
-    sspec = P(shard_axes, None, None)
+    sspec = P(shard_axes, None, None, None)
     arr_specs = ShardedIndexArrays(
         chunk_words=sspec, centroids=P(),
         ep_ids=P(shard_axes, None), ep_codes=P(shard_axes, None, None),

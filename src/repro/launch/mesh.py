@@ -1,29 +1,14 @@
 """Production mesh construction (functions only — importing this module
-never touches jax device state).
-
-``make_mesh_compat`` is the version-compat shim: newer JAX exposes
-``jax.sharding.AxisType`` and ``jax.make_mesh(..., axis_types=...)``;
-older releases (<= 0.4.x) have neither. All mesh construction in this
-repo (and in the subprocess-driven distributed tests) goes through the
-shim so the same code runs on both.
+never touches jax device state). Every mesh axis is `AxisType.Auto`.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh with AxisType.Auto on JAX versions that support it,
-    plain jax.make_mesh elsewhere."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except TypeError:
-        return jax.make_mesh(shape, axes)
+def _make_mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,9 +20,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for CPU multi-device tests (8 virtual devices)."""
-    return make_mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)

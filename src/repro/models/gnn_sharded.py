@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import GNNConfig
 
@@ -102,11 +101,11 @@ def sharded_full_loss_fn(mesh, cfg: GNNConfig, n_nodes: int,
         return loss_num / jnp.maximum(loss_den, 1.0), \
             acc_num / jnp.maximum(loss_den, 1.0)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(axes, None, None), P(), P()),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def loss_fn(params, batch):
         loss, acc = smapped(params, batch["feats"], batch["edges"],

@@ -119,7 +119,6 @@ def moe_apply_ep(p: dict, x: jax.Array, cfg: MoEConfig, *,
     mesh = act_sharding._MESH
     if mesh is None or "model" not in mesh.axis_names:
         return moe_apply(p, x, cfg, n_pad_experts=n_pad_experts)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     mways = mesh.shape["model"]
@@ -181,10 +180,10 @@ def moe_apply_ep(p: dict, x: jax.Array, cfg: MoEConfig, *,
     shared = p.get("shared")
     sh_specs = jax.tree.map(lambda _: P(), shared) if shared is not None \
         else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None), sh_specs),
         out_specs=(P(dp, None), P()),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)

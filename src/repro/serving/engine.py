@@ -47,7 +47,8 @@ def make_device_search_fn(index, layout, *, metric: str = "l2", L: int = 48,
         """Candidate full-precision vectors, bitcast out of the packed HBM
         chunk rows ON DEMAND — only (nq*r) rows per call ever materialize,
         never an (N, d) resident copy of the corpus."""
-        rows = index.chunk_words[ids.reshape(-1)]     # (nq*r, stride/4) i32
+        rows = index.chunk_words[ids.reshape(-1)]     # (nq*r, rows, 128)
+        rows = rows.reshape(rows.shape[0], -1)        # (nq*r, stride/4) i32
         by = jax.lax.bitcast_convert_type(
             rows, jnp.uint8).reshape(rows.shape[0], -1)
         vb = by[:, :layout.b_full]

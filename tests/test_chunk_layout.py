@@ -44,9 +44,14 @@ def test_block_alignment_no_straddle():
 
 
 def test_device_stride_alignment():
-    for dim, dt, R, m in [(48, "float32", 20, 12), (128, "uint8", 52, 32)]:
+    for dim, dt, R, m, stride in [(48, "float32", 20, 12, 1024),
+                                  (128, "uint8", 52, 32, 2048),
+                                  (128, "float32", 56, 128, 8192)]:
         lay = ChunkLayout("aisaq", dim, dt, R, m)
-        assert lay.device_stride % 128 == 0
+        # whole (rows, 128) int32 tiles, a row count HBM tiling stores as is
+        assert lay.device_stride == stride == lay.device_rows * 512
+        assert lay.device_rows in (1, 2, 4) or lay.device_rows % 8 == 0
+        assert lay.dev_off_pq + R * m <= lay.device_stride
         assert lay.dev_off_ids % 4 == 0 and lay.dev_off_pq % 4 == 0
 
 

@@ -34,12 +34,11 @@ cb = pq.train_codebooks(jax.random.PRNGKey(0), base, m=8, iters=6)
 cents = np.asarray(cb.centroids); codes = np.asarray(pq.encode(cb, base))
 lay = ChunkLayout('aisaq', 32, 'float32', 16, 8)
 shards = build_sharded(base, 4, R=16, L=32, seed=0)
-arrays = stack_shards(shards, cents, codes, lay)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((2, 4), ('data', 'model'))
+arrays = stack_shards(shards, cents, codes, lay, mesh)
 search = sharded_search_fn(mesh, k=10, L=48, w=4, max_hops=64, layout=lay, metric='l2', backend='ref')
-ash, qsh = input_sharding(mesh)
-arrays = jax.tree.map(lambda a, s: jax.device_put(a, s), arrays, ash)
+_, qsh = input_sharding(mesh)
 ids, dd = jax.jit(search)(arrays, jax.device_put(jnp.asarray(q), qsh))
 r1 = recall_at(np.asarray(ids), gt, 1); r10 = recall_at(np.asarray(ids), gt, 10)
 assert r1 >= 0.9 and r10 >= 0.85, (r1, r10)
@@ -118,16 +117,15 @@ print('pipeline OK')
 def test_compressed_grad_allreduce():
     run_py("""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.compression import compressed_psum
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ('data',))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((8,), ('data',))
 rng = np.random.default_rng(0)
 g = jnp.asarray(rng.normal(size=(8, 4096)).astype(np.float32))
 def local(gs):
     return compressed_psum({'g': gs[0]}, 'data')['g']
-out = shard_map(local, mesh=mesh, in_specs=(P('data', None),), out_specs=P(None), check_rep=False)(g)
+out = jax.shard_map(local, mesh=mesh, in_specs=(P('data', None),), out_specs=P(None), check_vma=False)(g)
 ref = g.mean(0)
 rel = float(jnp.abs(out - ref).max() / (jnp.abs(ref).max() + 1e-9))
 assert rel < 0.02, rel      # int8 grade
@@ -236,15 +234,13 @@ cb = pq.train_codebooks(jax.random.PRNGKey(0), base, m=8, iters=6)
 cents = np.asarray(cb.centroids); codes = np.asarray(pq.encode(cb, base))
 lay = ChunkLayout('aisaq', 32, 'float32', 16, 8)
 shards = build_sharded(base, 8, R=16, L=32, seed=0)
-arrays = stack_shards(shards, cents, codes, lay)
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 4), ('data', 'model'))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((2, 4), ('data', 'model'))
+arrays = stack_shards(shards, cents, codes, lay, mesh,
+                      shard_axes=('data', 'model'))
 search = sharded_search_fn(mesh, k=10, L=48, w=4, max_hops=64, layout=lay,
                            metric='l2', backend='ref', query_axes=(),
                            shard_axes=('data', 'model'), query_chunk=8)
-ash, qsh = input_sharding(mesh, query_axes=(None,), shard_axes=('data', 'model'))
-from jax.sharding import NamedSharding, PartitionSpec as P
-arrays = jax.tree.map(lambda a, s: jax.device_put(a, s), arrays, ash)
 ids, dd = jax.jit(search)(arrays, jnp.asarray(q))
 r1 = recall_at(np.asarray(ids), gt, 1)
 assert r1 >= 0.85, r1

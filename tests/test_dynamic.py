@@ -308,3 +308,29 @@ def test_concurrent_search_during_insert(small_built, tmp_path):
     ids, _ = r.search(base[201].astype(np.float32), 1, L=24)
     assert len(ids) == 1
     r.close()
+
+
+def test_search_takes_the_read_lock_once(small_built, tmp_path):
+    """The lock is writer-priority: a nested read acquire would queue behind
+    a waiting insert that waits for this very reader."""
+    from contextlib import contextmanager
+    p, base = _copy(small_built, tmp_path)
+    idx = DynamicHostIndex.load(p)
+    real, depth = idx._rw.read, [0, 0]
+
+    @contextmanager
+    def counted():
+        depth[0] += 1
+        depth[1] = max(depth)
+        try:
+            with real():
+                yield
+        finally:
+            depth[0] -= 1
+
+    idx._rw.read = counted
+    idx.delete(3)                     # tombstones force the widened search
+    ids, _ = idx.search(base[3].astype(np.float32), 5, L=24)
+    assert len(ids) == 5 and 3 not in ids.tolist()
+    assert depth[1] == 1
+    idx.close()

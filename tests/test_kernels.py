@@ -52,9 +52,7 @@ def test_fused_hop_sweep(dt, metric, R, m, dim):
         vecs = RNG.normal(size=(N, dim)).astype(np.float32)
     adj = RNG.integers(-1, N, (N, R)).astype(np.int32)
     codes = RNG.integers(0, 256, (N, m)).astype(np.uint8)
-    words = jnp.asarray(np.ascontiguousarray(
-        pack_chunks_device(vecs, adj, codes, lay)).view(np.int32)
-        .reshape(N, -1))
+    words = jnp.asarray(pack_chunks_device(vecs, adj, codes, lay))
     fids = jnp.asarray(RNG.integers(-1, N, (2, 4)).astype(np.int32))
     qs = jnp.asarray(RNG.normal(size=(2, dim)).astype(np.float32))
     cents = jnp.asarray(RNG.normal(size=(m, 256, dim // m))
@@ -104,24 +102,20 @@ def test_pq_adc_int8_error_bound(nq, n, m):
 
 def test_fused_hop_int8_variant():
     """§Perf adc-int8 in the fused hop kernel: error bound + identical ids."""
-    from repro.core.chunk_layout import ChunkLayout, pack_chunks_device
-    from repro.kernels.chunk_adc import fused_hop
     N, d, R, m = 150, 64, 24, 16
     lay = ChunkLayout("aisaq", d, "float32", R, m)
     vecs = RNG.normal(size=(N, d)).astype(np.float32)
     adj = RNG.integers(-1, N, (N, R)).astype(np.int32)
     codes = RNG.integers(0, 256, (N, m)).astype(np.uint8)
-    words = jnp.asarray(np.ascontiguousarray(
-        pack_chunks_device(vecs, adj, codes, lay)).view(np.int32)
-        .reshape(N, -1))
+    words = jnp.asarray(pack_chunks_device(vecs, adj, codes, lay))
     fids = jnp.asarray(RNG.integers(-1, N, (2, 4)).astype(np.int32))
     qs = jnp.asarray(RNG.normal(size=(2, d)).astype(np.float32))
     cents = jnp.asarray(RNG.normal(size=(m, 256, d // m)).astype(np.float32))
     lut = ref.pq_lut_ref(qs, cents, metric="l2")
-    _, i1, d1 = fused_hop(words, fids, lut, qs, layout=lay, metric="l2",
-                          interpret=True, quantized=True)
-    _, i2, d2 = fused_hop(words, fids, lut, qs, layout=lay, metric="l2",
-                          interpret=True, quantized=False)
+    _, i1, d1 = ops.fused_hop(words, fids, lut, qs, layout=lay, metric="l2",
+                              backend="pallas_interpret", adc_dtype="int8")
+    _, i2, d2 = ops.fused_hop(words, fids, lut, qs, layout=lay, metric="l2",
+                              backend="pallas_interpret", adc_dtype="f32")
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     fin = np.isfinite(np.asarray(d2))
     err = np.abs(np.asarray(d1)[fin] - np.asarray(d2)[fin]).max()
