@@ -1,0 +1,8 @@
+"""99th percentile latency of every request in the window, from its due
+time; a request that failed counts as missing every limit. A traced run
+reads its untraced window, as every run does."""
+from benchmarks.chip.metrics_common import latency_percentile
+
+
+def read(rec):
+    return latency_percentile(rec, 99)
