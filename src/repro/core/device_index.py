@@ -13,9 +13,11 @@ their frontier with -1 (the hop kernel emits +inf for those lanes).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
+import time
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -25,6 +27,12 @@ import numpy as np
 from repro.core.chunk_layout import ChunkLayout, chunk_matrix, \
     pack_chunks_device
 from repro.kernels import ops
+from repro.obs import trace as obs_trace
+from repro.obs.metrics import MetricsRegistry
+
+# every obs span of this process also lands on the profiler's host
+# timeline, beside the device ops, whenever a profile is captured
+obs_trace.set_profiler_mirror(jax.profiler.TraceAnnotation)
 
 # rows packed on the host per transfer: bounds a slab at 0.5 GB at sift1m
 # widths (1 GB at kilt-e5's) while the device table fills in place
@@ -57,39 +65,80 @@ def _put_slab(table: jax.Array, slab: jax.Array, start) -> jax.Array:
     return jax.lax.dynamic_update_slice_in_dim(table, slab, start, axis=0)
 
 
+@contextlib.contextmanager
+def _timed(counter, name: str):
+    """Span `name` over the block; its seconds are added to `counter`."""
+    t = time.perf_counter()
+    with obs_trace.span(name):
+        yield
+    counter.inc(time.perf_counter() - t)
+
+
+def _load_counters(registry: Optional[MetricsRegistry]):
+    reg = registry or MetricsRegistry()
+    return tuple(reg.counter(f"index_{phase}_seconds", help=doc,
+                             unit="seconds")
+                 for phase, doc in (
+                     ("pack", "host packing of chunk-table slabs"),
+                     ("put", "host-to-device transfers and slab writes"),
+                     ("entry", "picking the entry point (mean, argsort)")))
+
+
 def device_table(vectors: np.ndarray, graph: np.ndarray, codes: np.ndarray,
-                 layout: ChunkLayout, device=None) -> jax.Array:
+                 layout: ChunkLayout, device=None, *,
+                 registry: Optional[MetricsRegistry] = None) -> jax.Array:
     """Pack the (N, rows, 128) chunk table onto `device` slab by slab, so
-    neither host nor device ever holds a second full-size copy."""
+    neither host nor device ever holds a second full-size copy. Packing
+    and placing are timed into `registry`'s `index_pack_seconds` and
+    `index_put_seconds` (spans `index.pack`, `index.put`)."""
+    pack, put, _ = _load_counters(registry)
     n = vectors.shape[0]
     if n <= _SLAB_ROWS:
-        return jax.device_put(
-            pack_chunks_device(vectors, graph, codes, layout), device)
-    table = jnp.zeros((n, layout.device_rows, 128), jnp.int32, device=device)
+        with _timed(pack, "index.pack"):
+            slab = pack_chunks_device(vectors, graph, codes, layout)
+        with _timed(put, "index.put"):
+            return jax.device_put(slab, device)
+    with _timed(put, "index.put"):
+        table = jnp.zeros((n, layout.device_rows, 128), jnp.int32,
+                          device=device)
     for s in range(0, n, _SLAB_ROWS):
         e = min(n, s + _SLAB_ROWS)
-        slab = pack_chunks_device(vectors[s:e], graph[s:e], codes, layout)
-        table = _put_slab(table, jax.device_put(slab, device), s)
+        with _timed(pack, "index.pack"):
+            slab = pack_chunks_device(vectors[s:e], graph[s:e], codes,
+                                      layout)
+        with _timed(put, "index.put"):
+            table = _put_slab(table, jax.device_put(slab, device), s)
     return table
 
 
 def from_arrays(vectors: np.ndarray, graph: np.ndarray, centroids: np.ndarray,
                 codes: np.ndarray, *, mode: str = "aisaq",
-                block_bytes: int = 4096) -> Tuple[DeviceIndex, ChunkLayout]:
+                block_bytes: int = 4096,
+                registry: Optional[MetricsRegistry] = None
+                ) -> Tuple[DeviceIndex, ChunkLayout]:
+    """Place an index on the device. `registry` (default: a registry of
+    its own) gets `index_pack_seconds`, `index_put_seconds` and
+    `index_entry_seconds`; transfers still in flight when this returns are
+    not counted."""
     n, d = vectors.shape
     layout = ChunkLayout(
         mode=mode, dim=d,
         data_dtype="uint8" if vectors.dtype == np.uint8 else "float32",
         R=graph.shape[1], pq_m=codes.shape[1], block_bytes=block_bytes)
-    mean = vectors.astype(np.float32).mean(axis=0)
-    dd = ((vectors.astype(np.float32) - mean) ** 2).sum(axis=1)
-    ep = np.argsort(dd)[:1].astype(np.int32)
-    idx = DeviceIndex(
-        chunk_words=device_table(vectors, graph, codes, layout),
-        centroids=jnp.asarray(centroids, jnp.float32),
-        ep_ids=jnp.asarray(ep),
-        ep_codes=jnp.asarray(codes[ep].astype(np.int32)),
-        pq_codes=jnp.asarray(codes) if mode == "diskann" else None)
+    _, put, entry = _load_counters(registry)
+    with _timed(entry, "index.entry"):
+        mean = vectors.astype(np.float32).mean(axis=0)
+        dd = ((vectors.astype(np.float32) - mean) ** 2).sum(axis=1)
+        ep = np.argsort(dd)[:1].astype(np.int32)
+    chunk_words = device_table(vectors, graph, codes, layout,
+                               registry=registry)
+    with _timed(put, "index.put"):
+        idx = DeviceIndex(
+            chunk_words=chunk_words,
+            centroids=jnp.asarray(centroids, jnp.float32),
+            ep_ids=jnp.asarray(ep),
+            ep_codes=jnp.asarray(codes[ep].astype(np.int32)),
+            pq_codes=jnp.asarray(codes) if mode == "diskann" else None)
     return idx, layout
 
 
@@ -150,10 +199,161 @@ def _mask_intra_dups(ids: jax.Array) -> jax.Array:
     return dup.at[qi, order].set(dup_sorted)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "L", "w", "max_hops", "layout", "metric", "backend",
-                     "adc_dtype"))
+_STATIC = ("k", "L", "w", "max_hops", "layout", "metric", "backend",
+           "adc_dtype")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
+                 w: int = 4, max_hops: int = 128, layout: ChunkLayout,
+                 metric: str = "l2", backend: str = "auto",
+                 adc_dtype: str = "f32"):
+    """The search loop itself: `beam_search_device`'s outputs, then the
+    number of valid frontier slots expanded over all loop trips (at most
+    hops * nq * w; the hop kernel's grid runs every slot regardless).
+
+    Each phase runs under a `jax.named_scope` (`lut`, `init`, and per trip
+    `frontier`, `hop`, `pool`, `visited`, `trim`), so the compiled ops'
+    `op_name` metadata says which phase a device op belongs to."""
+    nq = queries.shape[0]
+    N = index.n
+    R = layout.R
+    with jax.named_scope("lut"):
+        lut = ops.build_lut(queries, index.centroids, metric=metric,
+                            backend=backend)
+        if layout.mode == "aisaq":
+            hop_operands = ops.hop_inputs(lut, queries, layout=layout,
+                                          backend=backend,
+                                          adc_dtype=adc_dtype)
+    with jax.named_scope("init"):
+        n_ep = index.ep_ids.shape[0]
+        ep_ids = jnp.broadcast_to(index.ep_ids[None, :], (nq, n_ep))
+        ep_d = jax.vmap(lambda l: jnp.sum(
+            jnp.take(l.reshape(-1),
+                     index.ep_codes + jnp.arange(lut.shape[1]) * lut.shape[2]),
+            axis=-1))(lut)                                    # (nq, n_ep)
+        pad = L - n_ep
+        cand_ids = jnp.concatenate(
+            [ep_ids, jnp.full((nq, pad), -1, jnp.int32)], axis=1)
+        cand_d = jnp.concatenate(
+            [ep_d, jnp.full((nq, pad), jnp.inf, jnp.float32)], axis=1)
+        cand_exp = jnp.concatenate(
+            [jnp.zeros((nq, n_ep), bool), jnp.ones((nq, pad), bool)], axis=1)
+        # visited set as a PACKED bitmask (N/32 uint32 words per query,
+        # §Perf "bitmask"): ids are pre-deduplicated before insertion, so
+        # each bit is added at most once and scatter-add == bitwise OR.
+        n_words = -(-N // 32)
+        qi = jnp.arange(nq)[:, None]
+        inserted = jnp.zeros((nq, n_words), jnp.uint32)
+        inserted = inserted.at[qi, ep_ids >> 5].add(
+            (jnp.uint32(1) << (ep_ids & 31).astype(jnp.uint32)))
+        pool_ids = jnp.full((nq, L), -1, jnp.int32)
+        pool_d = jnp.full((nq, L), jnp.inf, jnp.float32)
+
+    def cond(state):
+        cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops, _ = \
+            state
+        active = jnp.any(~cand_exp & jnp.isfinite(cand_d))
+        return active & (hops < max_hops)
+
+    def body(state):
+        (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops,
+         expanded) = state
+        # 1. frontier: top-w unexpanded by PQ distance
+        with jax.named_scope("frontier"):
+            sel = jnp.where(cand_exp, jnp.inf, cand_d)
+            negd, pos = jax.lax.top_k(-sel, w)                 # (nq, w)
+            fvalid = jnp.isfinite(negd)
+            fids = jnp.where(fvalid,
+                             jnp.take_along_axis(cand_ids, pos, axis=1), -1)
+            cand_exp = cand_exp.at[qi, pos].max(fvalid)
+            # counted per slot from fids, so the TPU compiler adds it to
+            # the fusion that makes fids: no extra launch per trip (a
+            # scalar sum of fvalid costs two)
+            expanded = expanded + (fids >= 0).astype(jnp.int32)
+        # 2. expand: chunk gather + parse + exact dist + neighbor ADC
+        with jax.named_scope("hop"):
+            if layout.mode == "aisaq":
+                exact, nids, nd = ops.hop(
+                    index.chunk_words, fids, hop_operands, layout=layout,
+                    metric=metric, backend=backend)
+            else:
+                # DiskANN-on-device: ids from chunks, codes from the
+                # resident (N, m) table — the memory-hungry baseline
+                # placement.
+                from repro.kernels import ref as _ref
+                exact, nids, _ = jax.vmap(functools.partial(
+                    _ref.fused_hop_ref, index.chunk_words, layout=layout,
+                    metric=metric))(fids, lut, queries)
+                flat = jnp.clip(nids.reshape(nq, -1), 0, N - 1)
+                codes = index.pq_codes[flat]               # (nq, w*R, m)
+                m, ks = lut.shape[1], lut.shape[2]
+                idxs = codes.astype(jnp.int32) + jnp.arange(m) * ks
+                nd = jax.vmap(lambda l, ii: jnp.take(l.reshape(-1), ii)
+                              .sum(-1))(lut, idxs).reshape(nq, w, R)
+                nd = jnp.where(nids >= 0, nd, jnp.inf)
+        # 3. re-rank pool (exact distances of expanded nodes)
+        with jax.named_scope("pool"):
+            pool_ids = jnp.concatenate([pool_ids, fids], axis=1)
+            pool_d = jnp.concatenate([pool_d, exact], axis=1)
+            npd, ppos = jax.lax.top_k(-pool_d, L)
+            pool_d = -npd
+            pool_ids = jnp.take_along_axis(pool_ids, ppos, axis=1)
+        # 4. neighbor insertion with dedup (packed-bitmask membership)
+        with jax.named_scope("visited"):
+            nids_f = nids.reshape(nq, w * R)
+            nd_f = nd.reshape(nq, w * R)
+            safe = jnp.clip(nids_f, 0, N - 1)
+            words = jnp.take_along_axis(inserted, safe >> 5, axis=1)
+            seen = ((words >> (safe & 31).astype(jnp.uint32)) & 1) \
+                .astype(bool)
+            bad = (nids_f < 0) | seen | _mask_intra_dups(nids_f)
+            nd_f = jnp.where(bad, jnp.inf, nd_f)
+            nids_f = jnp.where(bad, -1, nids_f)
+            safe = jnp.clip(nids_f, 0, N - 1)
+            bits = jnp.where(bad, jnp.uint32(0),
+                             jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
+            inserted = inserted.at[qi, safe >> 5].add(bits)
+        # 5. trim candidate list to L by PQ distance
+        with jax.named_scope("trim"):
+            all_ids = jnp.concatenate([cand_ids, nids_f], axis=1)
+            all_d = jnp.concatenate([cand_d, nd_f], axis=1)
+            all_exp = jnp.concatenate(
+                [cand_exp, jnp.ones_like(nids_f, bool) & ~jnp.isfinite(nd_f)],
+                axis=1)
+            negd2, cpos = jax.lax.top_k(-all_d, L)
+            cand_d = -negd2
+            cand_ids = jnp.take_along_axis(all_ids, cpos, axis=1)
+            cand_exp = jnp.take_along_axis(all_exp, cpos, axis=1)
+        return (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d,
+                hops + 1, expanded)
+
+    state = (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d,
+             jnp.array(0, jnp.int32), jnp.zeros((nq, w), jnp.int32))
+    state = jax.lax.while_loop(cond, body, state)
+    _, _, _, _, pool_ids, pool_d, hops, expanded = state
+    negd, pos = jax.lax.top_k(-pool_d, k)
+    return (jnp.take_along_axis(pool_ids, pos, axis=1), -negd, hops,
+            jnp.sum(expanded))
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _served_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
+                   w: int = 4, max_hops: int = 128, layout: ChunkLayout,
+                   metric: str = "l2", backend: str = "auto",
+                   adc_dtype: str = "f32"):
+    """The program a served call runs: `_beam_search`'s top-k ids,
+    flattened, then its loop trips and expanded slots, as the one int32
+    output. Each output of a TPU program costs the runtime about 0.1 ms a
+    call (v5e, 1 M rows, nq 16: 8.93 ms a call with this one output, 9.27
+    with `_beam_search`'s four), so the served call's program returns
+    only the buffer it fetches."""
+    ids, _, hops, expanded = _beam_search(
+        index, queries, k=k, L=L, w=w, max_hops=max_hops, layout=layout,
+        metric=metric, backend=backend, adc_dtype=adc_dtype)
+    return jnp.concatenate([ids.reshape(-1), jnp.stack([hops, expanded])])
+
+
 def beam_search_device(index: DeviceIndex, queries: jax.Array, *, k: int,
                        L: int, w: int = 4, max_hops: int = 128,
                        layout: ChunkLayout, metric: str = "l2",
@@ -163,106 +363,15 @@ def beam_search_device(index: DeviceIndex, queries: jax.Array, *, k: int,
     adc_dtype="int8" runs neighbor ADC through the int8 fused-hop kernel
     (2x MXU rate); the exact re-rank distances stay f32, so end recall is
     within quantization noise of the f32 path (aisaq mode only).
+
+    `beam_search_device.lower(...)` lowers the jitted program this runs
+    (`_beam_search`, whose fourth output is the count of expanded
+    frontier slots); the served path runs it inside `_served_search`.
     """
-    nq = queries.shape[0]
-    N = index.n
-    R = layout.R
-    lut = ops.build_lut(queries, index.centroids, metric=metric,
-                        backend=backend)
-    if layout.mode == "aisaq":
-        hop_operands = ops.hop_inputs(lut, queries, layout=layout,
-                                      backend=backend, adc_dtype=adc_dtype)
-    n_ep = index.ep_ids.shape[0]
-    ep_ids = jnp.broadcast_to(index.ep_ids[None, :], (nq, n_ep))
-    ep_d = jax.vmap(lambda l: jnp.sum(
-        jnp.take(l.reshape(-1),
-                 index.ep_codes + jnp.arange(lut.shape[1]) * lut.shape[2]),
-        axis=-1))(lut)                                    # (nq, n_ep)
-    pad = L - n_ep
-    cand_ids = jnp.concatenate(
-        [ep_ids, jnp.full((nq, pad), -1, jnp.int32)], axis=1)
-    cand_d = jnp.concatenate(
-        [ep_d, jnp.full((nq, pad), jnp.inf, jnp.float32)], axis=1)
-    cand_exp = jnp.concatenate(
-        [jnp.zeros((nq, n_ep), bool), jnp.ones((nq, pad), bool)], axis=1)
-    # visited set as a PACKED bitmask (N/32 uint32 words per query, §Perf
-    # "bitmask"): ids are pre-deduplicated before insertion, so each bit is
-    # added at most once and scatter-add == bitwise OR.
-    n_words = -(-N // 32)
-    qi = jnp.arange(nq)[:, None]
-    inserted = jnp.zeros((nq, n_words), jnp.uint32)
-    inserted = inserted.at[qi, ep_ids >> 5].add(
-        (jnp.uint32(1) << (ep_ids & 31).astype(jnp.uint32)))
-    pool_ids = jnp.full((nq, L), -1, jnp.int32)
-    pool_d = jnp.full((nq, L), jnp.inf, jnp.float32)
+    ids, d, hops, _ = _beam_search(
+        index, queries, k=k, L=L, w=w, max_hops=max_hops, layout=layout,
+        metric=metric, backend=backend, adc_dtype=adc_dtype)
+    return ids, d, hops
 
-    def cond(state):
-        cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops = state
-        active = jnp.any(~cand_exp & jnp.isfinite(cand_d))
-        return active & (hops < max_hops)
 
-    def body(state):
-        cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops = state
-        # 1. frontier: top-w unexpanded by PQ distance
-        sel = jnp.where(cand_exp, jnp.inf, cand_d)
-        negd, pos = jax.lax.top_k(-sel, w)                 # (nq, w)
-        fvalid = jnp.isfinite(negd)
-        fids = jnp.where(fvalid,
-                         jnp.take_along_axis(cand_ids, pos, axis=1), -1)
-        cand_exp = cand_exp.at[qi, pos].max(fvalid)
-        # 2. expand: chunk gather + parse + exact dist + neighbor ADC
-        if layout.mode == "aisaq":
-            exact, nids, nd = ops.hop(
-                index.chunk_words, fids, hop_operands, layout=layout,
-                metric=metric, backend=backend)
-        else:
-            # DiskANN-on-device: ids from chunks, codes from the resident
-            # (N, m) table — the memory-hungry baseline placement.
-            from repro.kernels import ref as _ref
-            exact, nids, _ = jax.vmap(functools.partial(
-                _ref.fused_hop_ref, index.chunk_words, layout=layout,
-                metric=metric))(fids, lut, queries)
-            flat = jnp.clip(nids.reshape(nq, -1), 0, N - 1)
-            codes = index.pq_codes[flat]                   # (nq, w*R, m)
-            m, ks = lut.shape[1], lut.shape[2]
-            idxs = codes.astype(jnp.int32) + jnp.arange(m) * ks
-            nd = jax.vmap(lambda l, ii: jnp.take(l.reshape(-1), ii).sum(-1)
-                          )(lut, idxs).reshape(nq, w, R)
-            nd = jnp.where(nids >= 0, nd, jnp.inf)
-        # 3. re-rank pool (exact distances of expanded nodes)
-        pool_ids = jnp.concatenate([pool_ids, fids], axis=1)
-        pool_d = jnp.concatenate([pool_d, exact], axis=1)
-        npd, ppos = jax.lax.top_k(-pool_d, L)
-        pool_d = -npd
-        pool_ids = jnp.take_along_axis(pool_ids, ppos, axis=1)
-        # 4. neighbor insertion with dedup (packed-bitmask membership)
-        nids_f = nids.reshape(nq, w * R)
-        nd_f = nd.reshape(nq, w * R)
-        safe = jnp.clip(nids_f, 0, N - 1)
-        words = jnp.take_along_axis(inserted, safe >> 5, axis=1)
-        seen = ((words >> (safe & 31).astype(jnp.uint32)) & 1).astype(bool)
-        bad = (nids_f < 0) | seen | _mask_intra_dups(nids_f)
-        nd_f = jnp.where(bad, jnp.inf, nd_f)
-        nids_f = jnp.where(bad, -1, nids_f)
-        safe = jnp.clip(nids_f, 0, N - 1)
-        bits = jnp.where(bad, jnp.uint32(0),
-                         jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
-        inserted = inserted.at[qi, safe >> 5].add(bits)
-        # 5. trim candidate list to L by PQ distance
-        all_ids = jnp.concatenate([cand_ids, nids_f], axis=1)
-        all_d = jnp.concatenate([cand_d, nd_f], axis=1)
-        all_exp = jnp.concatenate(
-            [cand_exp, jnp.ones_like(nids_f, bool) & ~jnp.isfinite(nd_f)],
-            axis=1)
-        negd2, cpos = jax.lax.top_k(-all_d, L)
-        cand_d = -negd2
-        cand_ids = jnp.take_along_axis(all_ids, cpos, axis=1)
-        cand_exp = jnp.take_along_axis(all_exp, cpos, axis=1)
-        return cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops + 1
-
-    state = (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d,
-             jnp.array(0, jnp.int32))
-    state = jax.lax.while_loop(cond, body, state)
-    _, _, _, _, pool_ids, pool_d, hops = state
-    negd, pos = jax.lax.top_k(-pool_d, k)
-    return jnp.take_along_axis(pool_ids, pos, axis=1), -negd, hops
+beam_search_device.lower = _beam_search.lower

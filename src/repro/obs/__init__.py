@@ -9,7 +9,8 @@ startup, and on the `core.traversal`/`core.block_cache` hot path.
             `merge_snapshots`, JSON + Prometheus-text exposition
   trace   — per-query span trees propagated router -> frame header ->
             worker -> traversal hops -> block-cache reads; Chrome
-            trace-event export; sampling knob; slow-query log
+            trace-event export; sampling knob; slow-query log; the
+            profiler mirror that puts spans on a device trace's clock
 
 See docs/observability.md for the metric tables and span hierarchy.
 """
@@ -18,12 +19,12 @@ from repro.obs.metrics import (COUNT_BUCKETS, DEFAULT_LATENCY_BUCKETS_S,
                                SearchMetrics, bucket_quantile,
                                merge_snapshots, to_prometheus_text)
 from repro.obs.trace import (Span, Tracer, activate, current_span, enabled,
-                             set_enabled, span)
+                             set_enabled, set_profiler_mirror, span)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "SearchMetrics",
     "DEFAULT_LATENCY_BUCKETS_S", "COUNT_BUCKETS", "bucket_quantile",
     "merge_snapshots", "to_prometheus_text",
     "Span", "Tracer", "activate", "current_span", "span",
-    "enabled", "set_enabled",
+    "enabled", "set_enabled", "set_profiler_mirror",
 ]

@@ -121,6 +121,16 @@ class Histogram:
             self.sum += v
             self.count += 1
 
+    def observe_many(self, values: Sequence[float]):
+        """`observe` each value under one lock acquisition."""
+        bounds, counts = self.bounds, self.counts
+        idx = [bisect_left(bounds, v) for v in values]
+        with self._lock:
+            for i in idx:
+                counts[i] += 1
+            self.sum += sum(values)
+            self.count += len(idx)
+
     def quantile(self, q: float) -> Optional[float]:
         with self._lock:
             counts = list(self.counts)

@@ -25,6 +25,15 @@ active.  The module-level `set_enabled(False)` kill switch short-
 circuits even that check (the <2% hot-path gate in
 `bench_search.py --quick` compares the two).
 
+Profiler mirror: a process that runs device code installs one hook,
+`set_profiler_mirror(factory)` (the device path installs
+`jax.profiler.TraceAnnotation`).  From then on every `span()` also opens
+`factory(name, **annotations)` for its block, with or without an active
+Tracer root, so the span lands on the profiler's host timeline next to
+the device ops whenever a profile is being captured.  This module never
+imports the profiler; host-only processes never install the hook and
+pay nothing for it.
+
 Slow-query log: a Tracer built with `slow_threshold_s` dumps the full
 span tree of any ROOT span that finishes over the threshold — to the
 bounded `slow_queries` deque always, and as one JSON line per query to
@@ -37,14 +46,15 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, List, Optional, Sequence
 
 __all__ = ["Span", "Tracer", "current_span", "span", "activate",
-           "set_enabled", "enabled"]
+           "set_enabled", "enabled", "set_profiler_mirror"]
 
 _tls = threading.local()
 _ENABLED = True      # global kill switch; see set_enabled()
+_MIRROR = None       # profiler annotation factory; see set_profiler_mirror()
 
 
 def set_enabled(flag: bool):
@@ -57,6 +67,15 @@ def set_enabled(flag: bool):
 
 def enabled() -> bool:
     return _ENABLED
+
+
+def set_profiler_mirror(factory):
+    """Install (or, with None, remove) the profiler mirror: a callable
+    `factory(name, **annotations)` returning a context manager that
+    `span()` enters around its block.  Returns the previous factory."""
+    global _MIRROR
+    prev, _MIRROR = _MIRROR, factory
+    return prev
 
 
 def current_span() -> Optional["Span"]:
@@ -84,20 +103,52 @@ def activate(sp: Optional["Span"]):
         st.pop()
 
 
-@contextmanager
 def span(name: str, **annotations):
-    """Open a child of the current span for the block; no-op (yields
-    None) when tracing is off or no span is active on this thread."""
+    """Open a child of the current span for the block, and the profiler
+    mirror's annotation when one is installed.  Yields the Span, or None
+    when tracing is off or no span is active on this thread.  With
+    neither a root nor a mirror it returns a shared no-op; with only the
+    mirror, the mirror's annotation alone (no generator frame), so the
+    device path's per-call spans cost a couple of microseconds."""
     parent = current_span()
-    if parent is None:
-        yield None
-        return
+    mirror = _MIRROR if _ENABLED else None
+    if parent is not None:
+        return _child(parent, name, annotations, mirror)
+    if mirror is None:
+        return _NOOP
+    return _Quiet(mirror(name, **annotations))
+
+
+_NOOP = nullcontext()
+
+
+class _Quiet:
+    """A mirror annotation that yields None, as a rootless span does."""
+
+    __slots__ = ("_cm",)
+
+    def __init__(self, cm):
+        self._cm = cm
+
+    def __enter__(self):
+        self._cm.__enter__()
+
+    def __exit__(self, *exc):
+        return self._cm.__exit__(*exc)
+
+
+@contextmanager
+def _child(parent: "Span", name: str, annotations: dict, mirror):
     sp = parent.tracer.start_span(name, parent=parent,
                                   annotations=annotations or None)
     st = _tls.stack
     st.append(sp)
     try:
-        yield sp
+        if mirror is None:
+            yield sp
+        else:
+            with mirror(name, **annotations):
+                yield sp
     finally:
         st.pop()
         sp.end()

@@ -23,11 +23,20 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro.obs.metrics import COUNT_BUCKETS, MetricsRegistry
+from repro.obs.trace import span
+
+#: bounds of the engine's time histograms: 100 µs .. 10 s, 20 per decade.
+#: Each bound is 12.2% above the one before, so a percentile interpolated
+#: inside its bucket is within 12.2% of the exact one.
+ENGINE_BUCKETS_S = tuple(10.0 ** (e / 20) for e in range(-80, 21))
+
 
 def make_device_search_fn(index, layout, *, metric: str = "l2", L: int = 48,
                           w: int = 4, max_hops: int = 128,
                           backend: str = "auto", adc_dtype: str = "f32",
-                          rerank: int = 0):
+                          rerank: int = 0,
+                          registry: Optional[MetricsRegistry] = None):
     """Wrap the device beam search into the `(queries, k) -> ids` callable
     `ServingEngine` consumes. `adc_dtype="int8"` serves via the int8
     fused-hop ADC kernel (2x MXU rate) — the public serving entry point for
@@ -37,11 +46,42 @@ def make_device_search_fn(index, layout, *, metric: str = "l2", L: int = 48,
     top-max(r, k) pool, their full-precision vectors are gathered from the
     HBM chunk table, and `kernels.rerank` (tiled Pallas matmul-with-epilogue
     on TPU, jnp ref elsewhere) rescores them exactly before the final
-    top-k."""
+    top-k.
+
+    Each call is the span `search.call` around `search.stage` (queries to
+    the device), `search.dispatch` (the jitted call, until it returns
+    asynchronously) and `search.fetch` (until the answers are on the
+    host), and counts into `registry` (default: a registry of its own):
+    `search_calls_total`, `search_loop_trips_total`, `search_slots_total`
+    (trips x nq x w) and `search_expansions_total` (valid frontier slots
+    expanded). The program returns the ids and both counts in one int32
+    buffer, so a call fetches one array. The callable's `lower(nq, k)`
+    lowers the program a call of `nq` queries runs."""
     import jax
     import jax.numpy as jnp
-    from repro.core.device_index import beam_search_device
+    from repro.core.device_index import _served_search
     from repro.kernels import ops
+
+    reg = registry or MetricsRegistry()
+    calls = reg.counter("search_calls_total", help="device search calls",
+                        unit="calls")
+    trips = reg.counter("search_loop_trips_total",
+                        help="beam-search loop trips, summed over calls",
+                        unit="trips")
+    slots = reg.counter("search_slots_total",
+                        help="frontier slots the hop kernel ran: "
+                             "trips x nq x w", unit="slots")
+    expansions = reg.counter("search_expansions_total",
+                             help="valid frontier slots expanded",
+                             unit="slots")
+
+    def _depth(k: int) -> int:
+        return max(int(rerank), k) if rerank else k
+
+    def _params(k: int) -> dict:
+        r = _depth(k)
+        return dict(k=r, L=max(L, r), w=w, max_hops=max_hops, layout=layout,
+                    metric=metric, backend=backend, adc_dtype=adc_dtype)
 
     def _gather_vecs(ids: "jax.Array") -> "jax.Array":
         """Candidate full-precision vectors, bitcast out of the packed HBM
@@ -57,19 +97,8 @@ def make_device_search_fn(index, layout, *, metric: str = "l2", L: int = 48,
         return jax.lax.bitcast_convert_type(
             vb.reshape(rows.shape[0], layout.dim, 4), jnp.float32)
 
-    def search(queries: np.ndarray, k: int) -> np.ndarray:
-        qj = jnp.asarray(queries)
-        if not rerank:
-            ids, _, _ = beam_search_device(
-                index, qj, k=k, L=max(L, k), w=w, max_hops=max_hops,
-                layout=layout, metric=metric, backend=backend,
-                adc_dtype=adc_dtype)
-            return np.asarray(ids)
-        r = max(int(rerank), k)
-        ids, _, _ = beam_search_device(
-            index, qj, k=r, L=max(L, r), w=w, max_hops=max_hops,
-            layout=layout, metric=metric, backend=backend,
-            adc_dtype=adc_dtype)
+    def _rerank(qj, ids, k: int):
+        r = _depth(k)
         nq = ids.shape[0]
         qf = qj.astype(jnp.float32)
         cand = _gather_vecs(jnp.clip(ids, 0, index.n - 1)) \
@@ -82,8 +111,36 @@ def make_device_search_fn(index, layout, *, metric: str = "l2", L: int = 48,
                        for i in range(nq)])                     # (nq, r)
         d = jnp.where(ids >= 0, d, jnp.inf)
         top = jnp.argsort(d, axis=1)[:, :k]
-        return np.asarray(jnp.take_along_axis(ids, top, axis=1))
+        return jnp.take_along_axis(ids, top, axis=1)
 
+    def search(queries: np.ndarray, k: int) -> np.ndarray:
+        nq = len(queries)
+        with span("search.call", nq=nq, k=k):
+            with span("search.stage"):
+                qj = jnp.asarray(queries)
+            with span("search.dispatch"):
+                # ids (nq * r) flat, then hops and expanded slots
+                out = _served_search(index, qj, **_params(k))
+                if rerank:
+                    ids = _rerank(qj, out[:-2].reshape(nq, -1), k)
+                    out = jnp.concatenate([ids.reshape(-1), out[-2:]])
+            with span("search.fetch"):
+                out = np.asarray(out)
+        hops, expanded = int(out[-2]), int(out[-1])
+        calls.inc()
+        trips.inc(hops)
+        slots.inc(hops * nq * w)
+        expansions.inc(expanded)
+        return out[:-2].reshape(nq, k)
+
+    def lower(nq: int, k: int):
+        """The beam-search program of a call of `nq` float32 queries,
+        lowered (`.compile().as_text()` names each device op and its
+        scope)."""
+        q = jax.ShapeDtypeStruct((nq, layout.dim), jnp.float32)
+        return _served_search.lower(index, q, **_params(k))
+
+    search.lower = lower
     return search
 
 
@@ -215,12 +272,21 @@ class ServingEngine:
 
     Multiple entries in `replicas` enable hedging; `switch_fn(corpus)` is
     called when the batch's corpus differs from the active one (the paper's
-    index-switch path)."""
+    index-switch path).
+
+    `registry` (default: one of the engine's own) gets the histograms
+    `engine_queue_wait_seconds` (submit until the batch is handed to the
+    search fn, per request), `engine_batch_size` and
+    `engine_latency_seconds` (submit until done, per answered request).
+    Each batch runs under the spans `engine.collect` (collecting it,
+    `max_wait_ms` included) and `engine.fanout` (search return until the
+    last request is woken)."""
 
     def __init__(self, search_fns: Dict[str, Callable], *,
                  max_batch: int = 32, max_wait_ms: float = 2.0,
                  hedge: int = 1, replicas: Optional[List[Callable]] = None,
-                 switch_fn: Optional[Callable[[str], float]] = None):
+                 switch_fn: Optional[Callable[[str], float]] = None,
+                 registry: Optional[MetricsRegistry] = None):
         self.search_fns = search_fns
         self.max_batch = max_batch
         self.max_wait = max_wait_ms / 1e3
@@ -229,7 +295,18 @@ class ServingEngine:
         self.switch_fn = switch_fn
         self.q: "queue.Queue[Request]" = queue.Queue()
         self._held: "deque[Request]" = deque()   # other-corpus holdover
-        self.metrics: List[float] = []
+        self.registry = registry or MetricsRegistry()
+        self._queue_wait = self.registry.histogram(
+            "engine_queue_wait_seconds", buckets=ENGINE_BUCKETS_S,
+            unit="seconds",
+            help="submit until the request's batch is handed to search")
+        self._batch_size = self.registry.histogram(
+            "engine_batch_size", buckets=COUNT_BUCKETS, unit="requests",
+            help="requests per batch handed to search")
+        self._latency = self.registry.histogram(
+            "engine_latency_seconds", buckets=ENGINE_BUCKETS_S,
+            unit="seconds",
+            help="submit until done, per answered request")
         self.switch_times: List[float] = []
         # hedge accounting: wasted = replicas that ran but lost the race,
         # failed = replicas that raised (the winner is the first SUCCESS)
@@ -357,14 +434,15 @@ class ServingEngine:
 
     def _loop_inner(self):
         while not self._stop:
-            batch = self._collect_batch()
+            with span("engine.collect"):
+                batch = self._collect_batch()
             if not batch:
                 continue
             if self._stop:               # stopped mid-collect: fail the
                 self._held.extend(batch)  # batch via the exit drain
                 break
             corpus = batch[0].corpus
-            err = None
+            err = t_dispatch = None
             try:
                 if self.switch_fn is not None \
                         and corpus != self._active_corpus:
@@ -373,6 +451,7 @@ class ServingEngine:
                 queries = np.stack([r.query for r in batch])
                 k = max(r.k for r in batch)
                 fn = self.search_fns[corpus]
+                t_dispatch = time.perf_counter()
                 if self.hedge > 1 and self.replicas:
                     ids = self._run_hedged(queries, k)
                 else:
@@ -384,15 +463,24 @@ class ServingEngine:
                         f"({len(batch)}, k)")
             except Exception as e:        # noqa: BLE001 — fail the batch,
                 err = e                   # never kill the engine thread
-            now = time.perf_counter()
-            for i, r in enumerate(batch):
-                r.t_done = now
-                if err is not None:
-                    r.error = err
-                else:
-                    r.result = ids[i, :r.k]
-                    self.metrics.append(r.latency_s)
-                r.event.set()
+            with span("engine.fanout", n=len(batch)):
+                now = time.perf_counter()
+                for i, r in enumerate(batch):
+                    r.t_done = now
+                    if err is not None:
+                        r.error = err
+                    else:
+                        r.result = ids[i, :r.k]
+                    r.event.set()
+            # observed once every request is woken, off the path from
+            # collecting a batch to dispatching it
+            if t_dispatch is not None:
+                self._batch_size.observe(len(batch))
+                self._queue_wait.observe_many(
+                    [t_dispatch - r.t_submit for r in batch])
+                if err is None:
+                    self._latency.observe_many(
+                        [now - r.t_submit for r in batch])
 
     def _drain(self, err: Exception):
         """Fail every request still parked in the holdover deque or the
@@ -415,13 +503,17 @@ class ServingEngine:
 
     # -- stats ----------------------------------------------------------------
     def latency_percentiles(self):
-        if not self.metrics:
+        """p50/p95/p99 of answered requests' latency in ms and their
+        count, read from `engine_latency_seconds`: each is interpolated
+        inside its bucket (`ENGINE_BUCKETS_S`), so it lies within 12.2% of
+        the exact percentile."""
+        h = self._latency
+        if not h.count:
             return {}
-        a = np.array(self.metrics)
-        return {"p50_ms": float(np.percentile(a, 50) * 1e3),
-                "p95_ms": float(np.percentile(a, 95) * 1e3),
-                "p99_ms": float(np.percentile(a, 99) * 1e3),
-                "n": len(a)}
+        return {"p50_ms": h.quantile(0.50) * 1e3,
+                "p95_ms": h.quantile(0.95) * 1e3,
+                "p99_ms": h.quantile(0.99) * 1e3,
+                "n": h.count}
 
     def stop(self):
         with self._submit_lock:

@@ -3,7 +3,10 @@ merging, span tracing, and trace-context propagation through the framed
 wire protocol (including corrupted-frame paths)."""
 import json
 import socket
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -67,6 +70,16 @@ def test_quantile_interpolates_within_bucket():
     v = h.quantile(0.5)
     assert 0.001 < v <= 0.002
     assert h.quantile(1.0) == pytest.approx(0.002)
+
+
+def test_observe_many_equals_one_observe_each():
+    rng = np.random.default_rng(1)
+    vals = rng.lognormal(-5, 2, size=300).tolist() + [0.0001, 100.0]
+    one, many = _hist(vals), M.Histogram({})
+    many.observe_many(vals)
+    many.observe_many([])
+    assert many.counts == one.counts and many.count == one.count
+    assert many.sum == pytest.approx(one.sum)
 
 
 def _snap_of(values, labels=None):
@@ -238,6 +251,86 @@ def test_spans_noop_without_active_parent_or_when_disabled():
     finally:
         T.set_enabled(True)
     root.end()
+
+
+class _Mirror:
+    """A profiler mirror that records what `span()` opens and closes."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name, **annotations):
+        mirror = self
+
+        class _Annotation:
+            def __enter__(self):
+                mirror.events.append(("open", name, annotations))
+
+            def __exit__(self, *exc):
+                mirror.events.append(("close", name, annotations))
+        return _Annotation()
+
+
+@pytest.fixture
+def mirror():
+    m = _Mirror()
+    prev = T.set_profiler_mirror(m)
+    yield m
+    T.set_profiler_mirror(prev)
+
+
+def test_span_without_mirror_or_root_opens_nothing():
+    prev = T.set_profiler_mirror(None)
+    try:
+        with T.span("orphan", nq=3) as sp:
+            assert sp is None and T.current_span() is None
+    finally:
+        T.set_profiler_mirror(prev)
+
+
+def test_mirror_opens_without_a_tracer_root(mirror):
+    assert T.current_span() is None
+    with T.span("search.call", nq=8) as sp:
+        assert sp is None
+        with T.span("search.fetch"):
+            pass
+    assert mirror.events == [
+        ("open", "search.call", {"nq": 8}),
+        ("open", "search.fetch", {}), ("close", "search.fetch", {}),
+        ("close", "search.call", {"nq": 8})]
+
+
+def test_mirror_and_tracer_span_together(mirror):
+    tr = T.Tracer()
+    root = tr.start_span("root")
+    with T.activate(root):
+        with T.span("child", shard=2) as sp:
+            assert sp is not None and T.current_span() is sp
+    root.end()
+    assert [e[:2] for e in mirror.events] == [("open", "child"),
+                                              ("close", "child")]
+    names = [d["name"] for d in tr.finished()]
+    assert names == ["child", "root"]
+
+
+def test_kill_switch_silences_the_mirror(mirror):
+    try:
+        T.set_enabled(False)
+        with T.span("engine.collect"):
+            pass
+    finally:
+        T.set_enabled(True)
+    assert mirror.events == []
+
+
+def test_obs_imports_without_jax_or_numpy():
+    code = ("import sys, repro.obs, repro.obs.trace, repro.obs.metrics; "
+            "print(sorted(m for m in ('jax', 'numpy') if m in sys.modules))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env={"PYTHONPATH": "src"},
+                         cwd=str(Path(__file__).resolve().parents[1]))
+    assert out.stdout.strip() == "[]"
 
 
 def test_deterministic_sampling_rate():

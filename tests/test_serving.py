@@ -27,6 +27,67 @@ def test_engine_batches_and_answers():
     eng.stop()
 
 
+def test_engine_histograms_and_spans():
+    """One queue wait per request, one batch size per batch, one latency
+    per answer; each batch is collected and fanned out under a span."""
+    from repro.obs import trace as T
+    from repro.obs.metrics import MetricsRegistry
+    spans = []
+
+    class _Annotation:
+        def __init__(self, name, **annotations):
+            spans.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    prev = T.set_profiler_mirror(_Annotation)
+    reg = MetricsRegistry()
+    try:
+        eng = ServingEngine({"default": _search_fn(0.002)}, max_batch=4,
+                            max_wait_ms=5.0, registry=reg)
+        assert eng.registry is reg
+        reqs = [eng.submit(np.ones(8, np.float32) * i) for i in range(10)]
+        for r in reqs:
+            assert r.event.wait(5.0) and r.result is not None
+        eng.stop()
+    finally:
+        T.set_profiler_mirror(prev)
+    snap = reg.snapshot()
+    wait = snap["engine_queue_wait_seconds"]["series"][0]
+    lat = snap["engine_latency_seconds"]["series"][0]
+    size = snap["engine_batch_size"]["series"][0]
+    assert wait["count"] == lat["count"] == 10
+    assert size["sum"] == 10 and size["count"] == spans.count("engine.fanout")
+    assert 3 <= size["count"] <= 10
+    assert spans.count("engine.collect") >= size["count"]
+    assert 0 <= wait["sum"] <= lat["sum"]
+    for p in ("p50", "p95", "p99"):
+        assert wait[p] <= lat[p]
+    pct = eng.latency_percentiles()
+    assert set(pct) == {"p50_ms", "p95_ms", "p99_ms", "n"}
+    assert pct["n"] == 10 and 0 < pct["p50_ms"] <= pct["p99_ms"]
+
+
+@pytest.mark.parametrize("sigma", [0.3, 1.5])
+def test_engine_latency_percentiles_within_a_bucket(sigma):
+    """`latency_percentiles()` interpolates in `ENGINE_BUCKETS_S`, whose
+    bounds are 12.2% apart: no percentile is further from the exact one."""
+    from repro.obs.metrics import Histogram
+    from repro.serving.engine import ENGINE_BUCKETS_S
+    ratio = max(b / a for a, b in zip(ENGINE_BUCKETS_S, ENGINE_BUCKETS_S[1:]))
+    assert ratio < 1.123
+    lat = np.random.default_rng(3).lognormal(np.log(0.015), sigma, 5000)
+    h = Histogram({}, buckets=ENGINE_BUCKETS_S)
+    h.observe_many(lat.tolist())
+    for q in (0.5, 0.95, 0.99):
+        exact = float(np.percentile(lat, 100 * q))
+        assert abs(h.quantile(q) / exact - 1) < ratio - 1, q
+
+
 def test_hedging_beats_straggler():
     fast, slow = _search_fn(0.002), _search_fn(0.25)
     hedged = ServingEngine({"default": slow}, hedge=2,
