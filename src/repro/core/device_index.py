@@ -187,16 +187,33 @@ def load_device_index(path: str) -> Tuple[DeviceIndex, ChunkLayout, str]:
 # ---------------------------------------------------------------------------
 
 
-def _mask_intra_dups(ids: jax.Array) -> jax.Array:
-    """(nq, K) int -> bool mask of duplicate (non-first) occurrences."""
-    order = jnp.argsort(ids, axis=1)
-    srt = jnp.take_along_axis(ids, order, axis=1)
-    dup_sorted = jnp.concatenate(
-        [jnp.zeros_like(srt[:, :1], dtype=bool), srt[:, 1:] == srt[:, :-1]],
-        axis=1)
-    dup = jnp.zeros_like(dup_sorted)
-    qi = jnp.arange(ids.shape[0])[:, None]
-    return dup.at[qi, order].set(dup_sorted)
+def _fresh(ep_ids: jax.Array, cand_ids: jax.Array,
+           nids: jax.Array) -> jax.Array:
+    """(nq, E) entry ids, (nq, L) candidate ids, (nq, K) neighbour ids ->
+    bool mask of the neighbours to offer the trim: a valid id that is not
+    an entry point, not in the list and not an earlier neighbour of this
+    trip.
+
+    This is the whole visited set, exactly, in O(L) state. The trim keeps
+    the top L of `[cand, new]` by PQ distance and `lax.top_k` breaks ties
+    toward the lower position, so the list's sorted distances never get
+    worse, and an id that was offered and is no longer listed lost to L
+    entries at least as close. Those entries, or better ones, fill today's
+    list and precede any neighbour in the concatenation. The hop computes
+    a neighbour's distance from the search's hop operands and the
+    neighbour's own codes, the same way in every slot and trip, so offered
+    again at the same distance it cannot re-enter. An entry point's first
+    distance comes from the f32 LUT instead, and int8 ADC or another
+    summation order can make it differ from its hop distance, so entry
+    points are never offered at all, as in a visited set that starts with
+    them."""
+    all_ids = jnp.concatenate([ep_ids, cand_ids, nids], axis=1)
+    P, K = all_ids.shape[1] - nids.shape[1], nids.shape[1]
+    # neighbour i (column P + i of all_ids) against every earlier column
+    earlier = jnp.arange(P + K)[None, :] < jnp.arange(P, P + K)[:, None]
+    dup = jnp.any((nids[:, :, None] == all_ids[:, None, :]) & earlier,
+                  axis=2)
+    return (nids >= 0) & ~dup
 
 
 _STATIC = ("k", "L", "w", "max_hops", "layout", "metric", "backend",
@@ -239,25 +256,16 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             [ep_d, jnp.full((nq, pad), jnp.inf, jnp.float32)], axis=1)
         cand_exp = jnp.concatenate(
             [jnp.zeros((nq, n_ep), bool), jnp.ones((nq, pad), bool)], axis=1)
-        # visited set as a PACKED bitmask (N/32 uint32 words per query,
-        # §Perf "bitmask"): ids are pre-deduplicated before insertion, so
-        # each bit is added at most once and scatter-add == bitwise OR.
-        n_words = -(-N // 32)
-        qi = jnp.arange(nq)[:, None]
-        inserted = jnp.zeros((nq, n_words), jnp.uint32)
-        inserted = inserted.at[qi, ep_ids >> 5].add(
-            (jnp.uint32(1) << (ep_ids & 31).astype(jnp.uint32)))
         pool_ids = jnp.full((nq, L), -1, jnp.int32)
         pool_d = jnp.full((nq, L), jnp.inf, jnp.float32)
 
     def cond(state):
-        cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops, _ = \
-            state
+        cand_ids, cand_d, cand_exp, pool_ids, pool_d, hops, _ = state
         active = jnp.any(~cand_exp & jnp.isfinite(cand_d))
         return active & (hops < max_hops)
 
     def body(state):
-        (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d, hops,
+        (cand_ids, cand_d, cand_exp, pool_ids, pool_d, hops,
          expanded) = state
         # 1. frontier: top-w unexpanded by PQ distance
         with jax.named_scope("frontier"):
@@ -266,7 +274,11 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             fvalid = jnp.isfinite(negd)
             fids = jnp.where(fvalid,
                              jnp.take_along_axis(cand_ids, pos, axis=1), -1)
-            cand_exp = cand_exp.at[qi, pos].max(fvalid)
+            # marked by comparison, not a scatter: XLA on a TPU runs
+            # scatter updates nearly one at a time
+            cand_exp = cand_exp | jnp.any(
+                (pos[:, :, None] == jnp.arange(L)) & fvalid[:, :, None],
+                axis=1)
             # counted per slot from fids, so the TPU compiler adds it to
             # the fusion that makes fids: no extra launch per trip (a
             # scalar sum of fvalid costs two)
@@ -299,21 +311,13 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             npd, ppos = jax.lax.top_k(-pool_d, L)
             pool_d = -npd
             pool_ids = jnp.take_along_axis(pool_ids, ppos, axis=1)
-        # 4. neighbor insertion with dedup (packed-bitmask membership)
+        # 4. neighbor dedup against the entry points, the candidate list
+        # and the trip's earlier neighbours (see `_fresh`)
         with jax.named_scope("visited"):
             nids_f = nids.reshape(nq, w * R)
-            nd_f = nd.reshape(nq, w * R)
-            safe = jnp.clip(nids_f, 0, N - 1)
-            words = jnp.take_along_axis(inserted, safe >> 5, axis=1)
-            seen = ((words >> (safe & 31).astype(jnp.uint32)) & 1) \
-                .astype(bool)
-            bad = (nids_f < 0) | seen | _mask_intra_dups(nids_f)
-            nd_f = jnp.where(bad, jnp.inf, nd_f)
-            nids_f = jnp.where(bad, -1, nids_f)
-            safe = jnp.clip(nids_f, 0, N - 1)
-            bits = jnp.where(bad, jnp.uint32(0),
-                             jnp.uint32(1) << (safe & 31).astype(jnp.uint32))
-            inserted = inserted.at[qi, safe >> 5].add(bits)
+            fresh = _fresh(ep_ids, cand_ids, nids_f)
+            nd_f = jnp.where(fresh, nd.reshape(nq, w * R), jnp.inf)
+            nids_f = jnp.where(fresh, nids_f, -1)
         # 5. trim candidate list to L by PQ distance
         with jax.named_scope("trim"):
             all_ids = jnp.concatenate([cand_ids, nids_f], axis=1)
@@ -325,13 +329,13 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             cand_d = -negd2
             cand_ids = jnp.take_along_axis(all_ids, cpos, axis=1)
             cand_exp = jnp.take_along_axis(all_exp, cpos, axis=1)
-        return (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d,
-                hops + 1, expanded)
+        return (cand_ids, cand_d, cand_exp, pool_ids, pool_d, hops + 1,
+                expanded)
 
-    state = (cand_ids, cand_d, cand_exp, inserted, pool_ids, pool_d,
+    state = (cand_ids, cand_d, cand_exp, pool_ids, pool_d,
              jnp.array(0, jnp.int32), jnp.zeros((nq, w), jnp.int32))
     state = jax.lax.while_loop(cond, body, state)
-    _, _, _, _, pool_ids, pool_d, hops, expanded = state
+    _, _, _, pool_ids, pool_d, hops, expanded = state
     negd, pos = jax.lax.top_k(-pool_d, k)
     return (jnp.take_along_axis(pool_ids, pos, axis=1), -negd, hops,
             jnp.sum(expanded))

@@ -108,8 +108,10 @@ def sharded_search_fn(mesh, *, k: int, L: int, w: int, max_hops: int,
     "mode B", index sharded over every axis for billion-scale tables);
     index shards over shard_axes. Output: (B, k) ids + dists like queries.
 
-    query_chunk > 0 processes queries in chunks inside lax.map, bounding the
-    per-query visited-bitmap working set (nq_chunk x N_shard bools).
+    query_chunk > 0 processes queries in chunks inside lax.map. The search
+    state per query is O(L), whatever N_shard; what grows with the batch
+    is each query's LUT and staged hop operands, and whether that needs
+    the chunking is not measured.
     """
     query_axes = _norm_axes(query_axes)
     qspec = P(query_axes, None) if query_axes else P(None, None)

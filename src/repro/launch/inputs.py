@@ -346,8 +346,9 @@ def _build_ann_cell(arch: ArchConfig, shape: ShapeConfig, mesh: Mesh,
         ep_codes=SDS((n_shards, cfg.n_ep, m), jnp.int32),
         offsets=SDS((n_shards,), jnp.int32))
     queries = SDS((shape.batch, cfg.dim), jnp.float32)
-    # packed visited bitmask (N_s/32 u32 per query) allows 4x larger query
-    # chunks at the same working set (§Perf "bitmask")
+    # mode B searches the replicated batch in chunks of 128 queries; the
+    # search state is O(L) a query, and whether the LUTs and staged hop
+    # operands of a whole batch need the chunking is not measured
     qchunk = 128 if (mode_b and shape.batch > 128) else 0
     search = sharded_search_fn(
         mesh, k=10, L=128, w=cfg.beamwidth, max_hops=cfg.max_hops,
