@@ -10,7 +10,9 @@ The topology is described inside a fixture only: the TPU library may be
 loaded by one process at a time, so describing it at import would break
 multi-worker collection.
 """
+import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +30,8 @@ from repro.kernels.rerank import rerank
 WIDTHS = {"sift1m": SIFT1M, "sift1b": SIFT1B, "kilt-e5": KILT_E5_22M}
 HBM_BYTES = 16 * 10 ** 9          # one v5e chip
 KERNEL = 'custom_call_target="tpu_custom_call"'
+CHIP_CONFIGS = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" \
+    / "configs"
 
 
 @pytest.fixture(scope="module")
@@ -100,18 +104,37 @@ def test_rerank_compiles(sds, name):
     assert KERNEL in c.as_text()
 
 
-@pytest.mark.parametrize("nq", [32, 1024], ids=["serve_q32", "serve_q1k"])
-def test_beam_search_compiles(sds, nq):
-    cfg = SIFT1M
+def _chip_search(name):
+    """(rows on the chip, L, max_hops) of a chip benchmark configuration."""
+    cfg = json.loads((CHIP_CONFIGS / f"{name}.json").read_text())
+    s = cfg["assumed"]["search"]
+    return cfg["n_vectors"], s["L"], s["max_hops"]
+
+
+# the deployments the chip benchmark searches, as (widths, rows on the
+# chip, L, max_hops): the whole of SIFT1M, and one of 44 shards of KILT E5
+# 22M, each at the search settings of its configuration file
+SEARCHES = {"sift1m": (SIFT1M, *_chip_search("aisaq-sift1m")),
+            "kilt_1of44": (KILT_E5_22M,
+                           *_chip_search("aisaq-kilt-e5-22m-1of44"))}
+
+
+@pytest.mark.parametrize("name,nq", [
+    ("sift1m", 32), ("sift1m", 1024), ("kilt_1of44", 32),
+    ("kilt_1of44", 1024)], ids=["serve_q32", "serve_q1k",
+                                "kilt_1of44-serve_q32",
+                                "kilt_1of44-serve_q1k"])
+def test_beam_search_compiles(sds, name, nq):
+    cfg, rows, L, max_hops = SEARCHES[name]
     lay = layout_for(cfg, "aisaq")
     index = DeviceIndex(
-        chunk_words=sds((cfg.n_vectors, lay.device_rows, 128), jnp.int32),
+        chunk_words=sds((rows, lay.device_rows, 128), jnp.int32),
         centroids=sds((cfg.pq_m, cfg.pq_ks, cfg.dim // cfg.pq_m),
                       jnp.float32),
         ep_ids=sds((1,), jnp.int32), ep_codes=sds((1, cfg.pq_m), jnp.int32))
     c = beam_search_device.lower(
-        index, sds((nq, cfg.dim), jnp.float32), k=10, L=48, w=4,
-        max_hops=128, layout=lay, metric=cfg.metric,
+        index, sds((nq, cfg.dim), jnp.float32), k=10, L=L, w=4,
+        max_hops=max_hops, layout=lay, metric=cfg.metric,
         backend="pallas").compile()
     assert c.as_text().count(KERNEL) >= 2           # LUT build + hop
     mem = c.memory_analysis()
