@@ -3,7 +3,8 @@
 The HBM-resident `(N, rows, 128)` int32 chunk table is the "storage tier"
 (DESIGN.md §2). Per-hop work — chunk gather, parse, inline-PQ ADC — is
 `kernels.ops.hop` (Pallas on TPU, jnp ref elsewhere) over the operands
-`kernels.ops.hop_inputs` stages once per search. Nothing
+`kernels.ops.hop_inputs` stages once per search, before the loop (an
+optimization barrier keeps the compiler from moving them into it). Nothing
 N-proportional is ever needed in VMEM: the only per-query fast-tier state is
 the (L,) candidate list, the (m, ks) LUT and the re-rank pool — the paper's
 `(R + n_ep)·b_pq` residency invariant, tier-shifted.
@@ -242,6 +243,9 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             hop_operands = ops.hop_inputs(lut, queries, layout=layout,
                                           backend=backend,
                                           adc_dtype=adc_dtype)
+            # jnp.tile inflates the staged LUT 4x; unpinned, XLA sinks that
+            # broadcast into the loop body and rebuilds it on every trip
+            hop_operands = jax.lax.optimization_barrier(hop_operands)
     with jax.named_scope("init"):
         n_ep = index.ep_ids.shape[0]
         ep_ids = jnp.broadcast_to(index.ep_ids[None, :], (nq, n_ep))

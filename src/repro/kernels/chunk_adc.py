@@ -79,12 +79,15 @@ class _Geom:
 
 def hop_inputs(lut: jax.Array, queries: jax.Array, *, layout: ChunkLayout,
                quantized: bool = False):
-    """Loop-invariant kernel operands, built once per search.
+    """Loop-invariant kernel operands, to be built once per search.
 
     lut (nq, m, ks) f32, queries (nq, d) -> (lut_lanes (nq, 4, ks, 128),
     q_tiles (nq, 4|1, vec_rows, 128) f32, scale (nq,) f32 or None).
     lut_lanes[n, k, v, l] = lut[n, 4*(l % g) + k, v]; quantized stages the
     int8 LUT values exactly in bf16 and returns the dequantization scale.
+    The lane tile is a size-inflating broadcast that XLA sinks into any
+    loop reading it unless the caller pins the result before the loop
+    (`jax.lax.optimization_barrier`).
     """
     geo = _Geom(layout)
     nq, m, ks = lut.shape

@@ -53,7 +53,11 @@ def adc(lut: jax.Array, codes: jax.Array, *, backend: str = "auto"
 
 def hop_inputs(lut: jax.Array, queries: jax.Array, *, layout: ChunkLayout,
                backend: str = "auto", adc_dtype: str = "f32"):
-    """Loop-invariant operands of `hop`, built once per search.
+    """Loop-invariant operands of `hop`, to be built once per search.
+
+    The TPU operands hold a lane-tiled LUT, a broadcast XLA sinks into a
+    loop that reads it: a caller looping over `hop` passes them through
+    `jax.lax.optimization_barrier` first, as `_beam_search` does.
 
     adc_dtype="int8" runs the §Perf adc-int8 path: per-query symmetric LUT
     quantization. The ref backend emulates the identical numerics (quantize

@@ -4,14 +4,18 @@ The TPU compiler refuses what interpret mode accepts: block shapes off the
 (8, 128) tiling, vector reshapes Mosaic cannot lay out, programs larger than
 HBM. Each test lowers a kernel (or the whole search) at Table-1 widths on
 `ShapeDtypeStruct`s placed on a described `v5e:2x2` device and asserts the
-Pallas kernel survived as a `tpu_custom_call`.
+Pallas kernel survived as a `tpu_custom_call`; the whole search also keeps
+its loop-invariant LUT staging out of the loop body (checked against a
+recorded compile where it was not).
 
 The topology is described inside a fixture only: the TPU library may be
 loaded by one process at a time, so describing it at import would break
 multi-worker collection.
 """
+import gzip
 import json
 import os
+import re
 from pathlib import Path
 
 import jax
@@ -32,6 +36,10 @@ HBM_BYTES = 16 * 10 ** 9          # one v5e chip
 KERNEL = 'custom_call_target="tpu_custom_call"'
 CHIP_CONFIGS = Path(__file__).resolve().parents[1] / "benchmarks" / "chip" \
     / "configs"
+# a served v5e search at nq 8, compiled before the LUT operands were pinned
+# outside the loop
+SUNK_LUT_HLO = CHIP_CONFIGS.parent / "tests" / "data" / "serve_q8.hlo.txt.gz"
+LUT_SCOPE = "jit(_beam_search)/lut/"
 
 
 @pytest.fixture(scope="module")
@@ -62,6 +70,42 @@ def sds(topo):
     def make(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     return make
+
+
+def loop_body_ops(hlo_text):
+    """The instruction lines of every while body in compiled HLO text,
+    with those of the computations each body calls (fusions, reducers,
+    nested loops)."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            head = line.split(" ", 2)
+            cur = (head[1] if head[0] == "ENTRY" else head[0]).lstrip("%")
+            comps[cur] = []
+        elif cur is not None and re.match(r"\s+(ROOT )?%[\w.\-]+ = ", line):
+            comps[cur].append(line)
+    todo = [m for lines in comps.values() for line in lines
+            for m in re.findall(r" while\(.*\bbody=%([\w.\-]+)", line)]
+    seen, ops = set(todo), []
+    while todo:
+        comp = todo.pop()
+        for line in comps.get(comp, ()):
+            ops.append(line)
+            for callee in re.findall(
+                    r"\b(?:calls|to_apply|condition|body)=%([\w.\-]+)", line):
+                if callee not in seen:
+                    seen.add(callee)
+                    todo.append(callee)
+    return ops
+
+
+def test_loop_body_ops_find_the_sunk_lut_tile():
+    """Positive control for the assertion in `test_beam_search_compiles`:
+    in a program compiled without the pin, the lane tile of the LUT runs
+    in the loop body as a broadcast under the `lut` scope."""
+    ops = loop_body_ops(gzip.decompress(SUNK_LUT_HLO.read_bytes()).decode())
+    sunk = [line for line in ops if LUT_SCOPE + "tile" in line]
+    assert any(" broadcast(" in line for line in sunk), sunk
 
 
 def _rows(cfg):
@@ -136,7 +180,12 @@ def test_beam_search_compiles(sds, name, nq):
         index, sds((nq, cfg.dim), jnp.float32), k=10, L=L, w=4,
         max_hops=max_hops, layout=lay, metric=cfg.metric,
         backend="pallas").compile()
-    assert c.as_text().count(KERNEL) >= 2           # LUT build + hop
+    text = c.as_text()
+    assert text.count(KERNEL) >= 2                  # LUT build + hop
+    body = loop_body_ops(text)
+    assert any("/while/body/hop/" in line for line in body)
+    # the staged LUT is built once, before the loop, never per trip
+    assert not [line for line in body if LUT_SCOPE in line]
     mem = c.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, used
