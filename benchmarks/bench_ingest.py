@@ -33,7 +33,7 @@ import time
 
 import numpy as np
 
-from repro.configs.base import IndexConfig
+from repro.core.index_config import IndexConfig
 from repro.core import pq
 from repro.core.build import build_index
 from repro.core.dynamic import DynamicHostIndex

@@ -180,7 +180,7 @@ def ensure_subcorpora(n_sub=5, m=DEFAULT_M):
     """Sub-corpus indices sharing one PQ-centroid set (Table 4)."""
     import jax
     from repro.core import pq
-    from repro.configs.base import IndexConfig
+    from repro.core.index_config import IndexConfig
     from repro.core.build import build_index
     base, _, _ = corpus()
     cb = pq.train_codebooks(jax.random.PRNGKey(m), base, m=m, iters=PQ_ITERS)

@@ -5,8 +5,7 @@ import sys
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", choices=["paper", "device", "search",
-                                       "serving"],
+    ap.add_argument("--only", choices=["paper", "search", "serving"],
                     default=None)
     ap.add_argument("--rebuild", action="store_true",
                     help="drop the cached corpus/graph/index artifacts and "
@@ -20,9 +19,6 @@ def main(argv=None) -> None:
     if args.only in (None, "paper"):
         from benchmarks.bench_paper import all_benchmarks as paper
         rows += paper()
-    if args.only in (None, "device"):
-        from benchmarks.bench_device import all_benchmarks as device
-        rows += device()
     if args.only in (None, "search"):
         from benchmarks.bench_search import all_benchmarks as search
         rows += search()

@@ -177,7 +177,7 @@ def kernel_parity(index, layout, metric, queries, backend):
 def phase_served(seed: int, *, n: int = N_SERVED, n_queries: int = N_QUERIES,
                  backend: str = "pallas") -> None:
     import jax.numpy as jnp
-    from repro.configs.aisaq_indices import SIFT1M
+    from repro.core.index_config import SIFT1M
     from repro.core import pq
     from repro.core.build import build_index
     from repro.core.device_index import load_device_index
@@ -236,7 +236,7 @@ def phase_resident(seed: int, *, n: int = N_RESIDENT,
                    batches=(("serve_q32", 32), ("serve_q1k", 1024)),
                    backend: str = "pallas") -> None:
     import jax
-    from repro.configs.aisaq_indices import SIFT1M
+    from repro.core.index_config import SIFT1M
     from repro.core.device_index import from_arrays
     from repro.data.vectors import make_clustered, make_queries
     from repro.serving.engine import make_device_search_fn
@@ -285,7 +285,7 @@ def phase_sharded(seed: int, *, n_shard: int = N_SHARD, nq: int = 32,
                   backend: str = "pallas") -> None:
     import jax
     import jax.numpy as jnp
-    from repro.configs.aisaq_indices import SIFT1B
+    from repro.core.index_config import SIFT1B
     from repro.core.chunk_layout import layout_for
     from repro.core.device_index import DeviceIndex, beam_search_device
     from repro.core.shard_math import merge_topk

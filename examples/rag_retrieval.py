@@ -21,7 +21,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import numpy as np
 
-from repro.configs.base import IndexConfig
+from repro.core.index_config import IndexConfig
 from repro.core import pq
 from repro.core.build import build_index
 from repro.data.vectors import make_clustered, make_queries

@@ -4,8 +4,8 @@ and automatic restart.
 
     PYTHONPATH=src python examples/train_lm.py [--steps 200]
 
-Uses the same launcher the dry-run validates at 512 chips, on a 1-device
-CPU mesh with a ~100M-parameter reduction of qwen3-1.7b.
+Runs the training launcher (`repro.launch.train`) on a 1-device CPU mesh
+with a ~100M-parameter reduction of qwen3-1.7b.
 """
 import argparse
 import os

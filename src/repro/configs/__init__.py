@@ -1,5 +1,5 @@
 from repro.configs.base import (  # noqa: F401
-    ArchConfig, GNNConfig, IndexConfig, LMConfig, MoEConfig, RecsysConfig,
-    ShapeConfig, LM_SHAPES, GNN_SHAPES, REC_SHAPES, ANN_SHAPES,
+    ArchConfig, GNNConfig, LMConfig, MoEConfig, RecsysConfig,
+    ShapeConfig, LM_SHAPES, GNN_SHAPES, REC_SHAPES,
 )
-from repro.configs.registry import get_arch, list_archs, ASSIGNED_ARCHS  # noqa: F401
+from repro.configs.registry import get_arch, ASSIGNED_ARCHS  # noqa: F401

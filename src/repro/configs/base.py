@@ -1,4 +1,4 @@
-"""Config dataclasses for every architecture family + ANN index configs.
+"""Config dataclasses for every architecture family of the training stack.
 
 Everything is a frozen dataclass so configs hash/compare cleanly and can be
 used as static args to jit. Each assigned architecture gets one module in
@@ -138,35 +138,6 @@ class RecsysConfig:
         return dataclasses.replace(self, **kw)
 
 
-@dataclass(frozen=True)
-class IndexConfig:
-    """AiSAQ / DiskANN index build + search parameters (paper Table 1)."""
-
-    name: str
-    n_vectors: int
-    dim: int
-    data_dtype: str = "float32"     # float32 | uint8 (SIFT1B is uint8)
-    metric: str = "l2"              # l2 | mips
-    R: int = 56                     # max outdegree
-    pq_m: int = 128                 # number of PQ subvectors == b_pq bytes
-    pq_ks: int = 256                # centroids per subquantizer (1 byte codes)
-    n_ep: int = 1                   # entry points kept resident
-    block_bytes: int = 4096         # LBA block size B
-    beamwidth: int = 4              # paper fixes w=4
-    build_L: int = 96               # candidate list size during build
-    alpha: float = 1.2              # RobustPrune distance slack
-    max_hops: int = 256             # while_loop bound on device backend
-    mode: str = "aisaq"             # aisaq | diskann (placement policy)
-
-    @property
-    def b_full(self) -> int:
-        itemsize = 1 if self.data_dtype == "uint8" else 4
-        return self.dim * itemsize
-
-    def scaled(self, **kw) -> "IndexConfig":
-        return dataclasses.replace(self, **kw)
-
-
 # ---------------------------------------------------------------------------
 # shapes
 # ---------------------------------------------------------------------------
@@ -188,7 +159,7 @@ class ShapeConfig:
     batch_nodes: int = 0
     fanout: Tuple[int, ...] = ()
     batch_graphs: int = 0
-    # recsys / ann
+    # recsys
     batch: int = 0
     n_candidates: int = 0
 
@@ -217,11 +188,6 @@ REC_SHAPES = (
     ShapeConfig("retrieval_cand", "rec_retrieval", batch=1, n_candidates=1_000_000),
 )
 
-ANN_SHAPES = (
-    ShapeConfig("serve_q32", "ann_search", batch=32),
-    ShapeConfig("serve_q1k", "ann_search", batch=1024),
-)
-
 
 # ---------------------------------------------------------------------------
 # arch container
@@ -231,7 +197,7 @@ ANN_SHAPES = (
 @dataclass(frozen=True)
 class ArchConfig:
     arch_id: str
-    family: str                     # lm | gnn | recsys | ann
+    family: str                     # lm | gnn | recsys
     model: object
     shapes: Tuple[ShapeConfig, ...]
     skip_shapes: Tuple[str, ...] = ()

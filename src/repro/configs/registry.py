@@ -21,10 +21,6 @@ _REGISTRY: Dict[str, tuple] = {
     "sasrec": ("repro.configs.sasrec", "ARCH"),
     "dcn-v2": ("repro.configs.dcn_v2", "ARCH"),
     "wide-deep": ("repro.configs.wide_deep", "ARCH"),
-    # paper's own indices (extra)
-    "aisaq-sift1m": ("repro.configs.aisaq_indices", "ARCH_SIFT1M"),
-    "aisaq-sift1b": ("repro.configs.aisaq_indices", "ARCH_SIFT1B"),
-    "aisaq-kilt-e5": ("repro.configs.aisaq_indices", "ARCH_KILT"),
 }
 
 ASSIGNED_ARCHS: List[str] = [
@@ -41,7 +37,3 @@ def get_arch(arch_id: str) -> ArchConfig:
         raise KeyError(
             f"unknown arch {arch_id!r}; known: {sorted(_REGISTRY)}") from None
     return getattr(importlib.import_module(mod_name), attr)
-
-
-def list_archs(include_extra: bool = True) -> List[str]:
-    return list(_REGISTRY) if include_extra else list(ASSIGNED_ARCHS)

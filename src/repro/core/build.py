@@ -8,7 +8,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.configs.base import IndexConfig
+from repro.core.index_config import IndexConfig
 from repro.core import pq
 from repro.core.index_io import write_index
 from repro.core.vamana import build_vamana, medoid

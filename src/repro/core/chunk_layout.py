@@ -140,7 +140,7 @@ class ChunkLayout:
 
 
 def layout_for(index_cfg, mode: str | None = None) -> ChunkLayout:
-    """Build a ChunkLayout from an :class:`repro.configs.base.IndexConfig`."""
+    """Build a ChunkLayout from an :class:`repro.core.index_config.IndexConfig`."""
     return ChunkLayout(
         mode=mode or index_cfg.mode, dim=index_cfg.dim,
         data_dtype=index_cfg.data_dtype, R=index_cfg.R, pq_m=index_cfg.pq_m,

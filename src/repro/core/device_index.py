@@ -45,7 +45,6 @@ class DeviceIndex(NamedTuple):
     centroids: jax.Array          # (m, ks, dsub) f32
     ep_ids: jax.Array             # (n_ep,) int32
     ep_codes: jax.Array           # (n_ep, m) int32
-    pq_codes: Optional[jax.Array] = None   # (N, m) — diskann mode ONLY
 
     @property
     def n(self) -> int:
@@ -56,8 +55,6 @@ class DeviceIndex(NamedTuple):
         m, ks = self.centroids.shape[0], self.centroids.shape[1]
         per_q = 4 * (m * ks + 3 * L)          # LUT + candidate list + pool
         resident = self.centroids.size * 4 + self.ep_codes.size * 4
-        if self.pq_codes is not None:         # DiskANN keeps ALL codes hot
-            resident += self.pq_codes.size * self.pq_codes.dtype.itemsize
         return int(resident + per_q * n_queries)
 
 
@@ -120,7 +117,11 @@ def from_arrays(vectors: np.ndarray, graph: np.ndarray, centroids: np.ndarray,
     """Place an index on the device. `registry` (default: a registry of
     its own) gets `index_pack_seconds`, `index_put_seconds` and
     `index_entry_seconds`; transfers still in flight when this returns are
-    not counted."""
+    not counted. The device search runs the AiSAQ layout only: a DiskANN
+    index (neighbour codes outside the chunk) raises ValueError."""
+    if mode != "aisaq":
+        raise ValueError(f"the device index holds aisaq chunks only, "
+                         f"not {mode!r}")
     n, d = vectors.shape
     layout = ChunkLayout(
         mode=mode, dim=d,
@@ -138,8 +139,7 @@ def from_arrays(vectors: np.ndarray, graph: np.ndarray, centroids: np.ndarray,
             chunk_words=chunk_words,
             centroids=jnp.asarray(centroids, jnp.float32),
             ep_ids=jnp.asarray(ep),
-            ep_codes=jnp.asarray(codes[ep].astype(np.int32)),
-            pq_codes=jnp.asarray(codes) if mode == "diskann" else None)
+            ep_codes=jnp.asarray(codes[ep].astype(np.int32)))
     return idx, layout
 
 
@@ -234,18 +234,15 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
     `frontier`, `hop`, `pool`, `visited`, `trim`), so the compiled ops'
     `op_name` metadata says which phase a device op belongs to."""
     nq = queries.shape[0]
-    N = index.n
     R = layout.R
     with jax.named_scope("lut"):
         lut = ops.build_lut(queries, index.centroids, metric=metric,
                             backend=backend)
-        if layout.mode == "aisaq":
-            hop_operands = ops.hop_inputs(lut, queries, layout=layout,
-                                          backend=backend,
-                                          adc_dtype=adc_dtype)
-            # jnp.tile inflates the staged LUT 4x; unpinned, XLA sinks that
-            # broadcast into the loop body and rebuilds it on every trip
-            hop_operands = jax.lax.optimization_barrier(hop_operands)
+        hop_operands = ops.hop_inputs(lut, queries, layout=layout,
+                                      backend=backend, adc_dtype=adc_dtype)
+        # jnp.tile inflates the staged LUT 4x; unpinned, XLA sinks that
+        # broadcast into the loop body and rebuilds it on every trip
+        hop_operands = jax.lax.optimization_barrier(hop_operands)
     with jax.named_scope("init"):
         n_ep = index.ep_ids.shape[0]
         ep_ids = jnp.broadcast_to(index.ep_ids[None, :], (nq, n_ep))
@@ -289,25 +286,9 @@ def _beam_search(index: DeviceIndex, queries: jax.Array, *, k: int, L: int,
             expanded = expanded + (fids >= 0).astype(jnp.int32)
         # 2. expand: chunk gather + parse + exact dist + neighbor ADC
         with jax.named_scope("hop"):
-            if layout.mode == "aisaq":
-                exact, nids, nd = ops.hop(
-                    index.chunk_words, fids, hop_operands, layout=layout,
-                    metric=metric, backend=backend)
-            else:
-                # DiskANN-on-device: ids from chunks, codes from the
-                # resident (N, m) table — the memory-hungry baseline
-                # placement.
-                from repro.kernels import ref as _ref
-                exact, nids, _ = jax.vmap(functools.partial(
-                    _ref.fused_hop_ref, index.chunk_words, layout=layout,
-                    metric=metric))(fids, lut, queries)
-                flat = jnp.clip(nids.reshape(nq, -1), 0, N - 1)
-                codes = index.pq_codes[flat]               # (nq, w*R, m)
-                m, ks = lut.shape[1], lut.shape[2]
-                idxs = codes.astype(jnp.int32) + jnp.arange(m) * ks
-                nd = jax.vmap(lambda l, ii: jnp.take(l.reshape(-1), ii)
-                              .sum(-1))(lut, idxs).reshape(nq, w, R)
-                nd = jnp.where(nids >= 0, nd, jnp.inf)
+            exact, nids, nd = ops.hop(
+                index.chunk_words, fids, hop_operands, layout=layout,
+                metric=metric, backend=backend)
         # 3. re-rank pool (exact distances of expanded nodes)
         with jax.named_scope("pool"):
             pool_ids = jnp.concatenate([pool_ids, fids], axis=1)
@@ -366,11 +347,11 @@ def beam_search_device(index: DeviceIndex, queries: jax.Array, *, k: int,
                        L: int, w: int = 4, max_hops: int = 128,
                        layout: ChunkLayout, metric: str = "l2",
                        backend: str = "auto", adc_dtype: str = "f32"):
-    """Batched DiskANN/AiSAQ beam search. Returns (topk_ids, topk_d, hops).
+    """Batched AiSAQ beam search. Returns (topk_ids, topk_d, hops).
 
     adc_dtype="int8" runs neighbor ADC through the int8 fused-hop kernel
     (2x MXU rate); the exact re-rank distances stay f32, so end recall is
-    within quantization noise of the f32 path (aisaq mode only).
+    within quantization noise of the f32 path.
 
     `beam_search_device.lower(...)` lowers the jitted program this runs
     (`_beam_search`, whose fourth output is the count of expanded

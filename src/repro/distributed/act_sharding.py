@@ -6,9 +6,10 @@ global batch per device and shard d_model instead (observed: 30+ GB of
 f32[256,4096,·] temps). `constrain(x, name)` pins the batch/dp sharding at
 the few points that anchor propagation.
 
-The policy is process-global and set by the launcher (dryrun/train/serve)
-via `set_policy(mesh, ...)`; model code stays mesh-agnostic. When no policy
-is active (CPU unit tests), constrain() is the identity.
+The policy is process-global and set by the training launcher
+(`launch/train.py`) via `set_policy(mesh, ...)`; model code stays
+mesh-agnostic. When no policy is active (CPU unit tests), constrain() is
+the identity.
 """
 from __future__ import annotations
 

@@ -104,13 +104,8 @@ def fused_hop_ref(chunk_words: jax.Array, frontier_ids: jax.Array,
         exact = jnp.sum(diff * diff, axis=-1)
     exact = jnp.where(fvalid, exact, jnp.inf)
     nvalid = (ids >= 0) & fvalid[:, None]
-    if layout.mode == "aisaq":
-        d = pq_adc_ref(lut, codes.reshape(w * layout.R, layout.pq_m))
-        d = d.reshape(w, layout.R)
-    else:
-        d = None  # diskann device mode resolves codes outside (RAM table)
-    if d is not None:
-        d = jnp.where(nvalid, d, jnp.inf)
+    d = pq_adc_ref(lut, codes.reshape(w * layout.R, layout.pq_m))
+    d = jnp.where(nvalid, d.reshape(w, layout.R), jnp.inf)
     ids = jnp.where(nvalid, ids, -1)
     return exact, ids, d
 

@@ -32,7 +32,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    from repro.configs.base import IndexConfig
+    from repro.core.index_config import IndexConfig
     from repro.core.build import build_index
     from repro.data.vectors import make_clustered
 

@@ -40,7 +40,7 @@ def main(argv=None):
         base = None
     else:
         print("no corpora given — building a demo index ...")
-        from repro.configs.base import IndexConfig
+        from repro.core.index_config import IndexConfig
         from repro.core.build import build_index
         dim = 64
         base = make_clustered(4000, dim, seed=0)

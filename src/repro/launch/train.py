@@ -60,7 +60,6 @@ def build_trainer(arch_id: str, shape_name: str, *, mesh=None,
                                   arch.model.vocab_sizes)))
             shape = dataclasses.replace(shape, batch=min(shape.batch, 64))
     set_policy(mesh)
-    from repro.launch.inputs import _make_init
     init_fn = _make_init(arch, shape, mesh or _FakeMesh())
     opt = default_optimizer(total_steps=steps, base_lr=lr)
     opt_init, _ = opt
@@ -109,6 +108,18 @@ def build_trainer(arch_id: str, shape_name: str, *, mesh=None,
         jax.jit(step_fn, in_shardings=(shardings, None),
                 out_shardings=(shardings, None), donate_argnums=(0,))
     return arch, state_init, jit_step, data_gen, shardings
+
+
+def _make_init(arch, shape, mesh):
+    if arch.family == "lm":
+        from repro.models.transformer import init_lm
+        ep = mesh.shape.get("model", 1)
+        return functools.partial(init_lm, cfg=arch.model, ep=ep)
+    if arch.family == "gnn":
+        from repro.models.gnn import init_gnn
+        return functools.partial(init_gnn, cfg=arch.model, d_feat=shape.d_feat)
+    from repro.models.recsys import init_recsys
+    return functools.partial(init_recsys, cfg=arch.model)
 
 
 class _FakeMesh:

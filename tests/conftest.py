@@ -1,7 +1,8 @@
 import os
 import sys
 
-# tests must see exactly ONE device (dry-run owns the 512-device env)
+# tests must see exactly ONE device (multi-device tests start their own
+# subprocesses with XLA_FLAGS of their own)
 os.environ.pop("XLA_FLAGS", None)
 
 import jax

@@ -23,12 +23,11 @@ def _device_search(small_corpus, built_graph, pq_artifacts, mode):
 
 def test_device_recall_both_modes(small_corpus, built_graph, pq_artifacts):
     base, q, gt = small_corpus
-    for mode in ("aisaq", "diskann"):
-        _, ids, hops = _device_search(small_corpus, built_graph,
-                                      pq_artifacts, mode)
-        assert recall_at(ids, gt, 1) >= 0.9, mode
-        assert recall_at(ids, gt, 10) >= 0.8, mode
-        assert 0 < hops
+    _, ids, hops = _device_search(small_corpus, built_graph, pq_artifacts,
+                                  "aisaq")
+    assert recall_at(ids, gt, 1) >= 0.9
+    assert recall_at(ids, gt, 10) >= 0.8
+    assert 0 < hops
 
 
 def test_device_matches_host_results(small_corpus, built_graph, pq_artifacts,
@@ -49,20 +48,19 @@ def test_device_matches_host_results(small_corpus, built_graph, pq_artifacts,
 def test_fast_tier_residency_invariant(small_corpus, built_graph,
                                        pq_artifacts):
     """The paper's invariant, tier-shifted: AiSAQ fast-tier bytes are
-    independent of N; DiskANN's grow with N (the (N, m) code table)."""
+    independent of N. The device holds no DiskANN index (its neighbour
+    codes would be an (N, m) table outside the chunks)."""
     base, q, _ = small_corpus
     cents, codes = pq_artifacts
     idx_a, _ = from_arrays(base, built_graph, cents, codes, mode="aisaq")
-    idx_d, _ = from_arrays(base, built_graph, cents, codes, mode="diskann")
-    n, m = codes.shape
     fa = idx_a.fast_tier_bytes(1, 40)
-    fd = idx_d.fast_tier_bytes(1, 40)
-    assert fd - fa == n * m * codes.dtype.itemsize
-    # halving N halves only the DiskANN side
-    half = n // 2
+    half = codes.shape[0] // 2
     g = np.clip(built_graph[:half], -1, half - 1)
     idx_a2, _ = from_arrays(base[:half], g, cents, codes[:half], mode="aisaq")
+    assert idx_a2.n == half
     assert idx_a2.fast_tier_bytes(1, 40) == fa
+    with pytest.raises(ValueError):
+        from_arrays(base, built_graph, cents, codes, mode="diskann")
 
 
 def _counts(reg):
@@ -215,8 +213,7 @@ def _hop_distances(idx, lay, q, lut, *, metric, backend, adc):
 
 @pytest.mark.parametrize("metric,mode,backend,adc", [
     (metric, "aisaq", backend, adc) for metric in ("l2", "mips")
-    for backend in ("ref", "pallas_interpret") for adc in ("f32", "int8")]
-    + [("l2", "diskann", "ref", "f32"), ("mips", "diskann", "ref", "f32")])
+    for backend in ("ref", "pallas_interpret") for adc in ("f32", "int8")])
 def test_list_dedup_matches_exact_seen_set(metric, mode, backend, adc):
     """Dedup against the candidate list alone gives the answers of an exact
     visited set, on an index where dropped ids, the entry point among them,
@@ -237,12 +234,8 @@ def test_list_dedup_matches_exact_seen_set(metric, mode, backend, adc):
     flat = np.asarray(lut).reshape(len(q), m * ks)
     seed_d = flat[:, np.asarray(idx.ep_codes[0]) + np.arange(m) * ks] \
         .sum(axis=1, dtype=np.float32)
-    if mode == "aisaq":
-        nbr_d = _hop_distances(idx, lay, jnp.asarray(q), lut, metric=metric,
-                               backend=backend, adc=adc)
-    else:
-        nbr_d = flat[:, codes.astype(np.int64) + np.arange(m) * ks] \
-            .sum(axis=2, dtype=np.float32)
+    nbr_d = _hop_distances(idx, lay, jnp.asarray(q), lut, metric=metric,
+                           backend=backend, adc=adc)
     want = []
     for i, qq in enumerate(q.astype(np.float64)):
         def pq(v, first, i=i):
@@ -280,7 +273,7 @@ def test_list_dedup_matches_exact_seen_set(metric, mode, backend, adc):
 
 @pytest.mark.parametrize("mode,backend,adc", [
     ("aisaq", backend, adc) for backend in ("ref", "pallas_interpret")
-    for adc in ("f32", "int8")] + [("diskann", "ref", "f32")])
+    for adc in ("f32", "int8")])
 def test_entry_point_is_never_offered_again(mode, backend, adc):
     """An entry point is seeded from the LUT, not by the hop, so its two PQ
     distances may differ (int8 ADC, another summation order). Seeded here
@@ -318,7 +311,7 @@ def test_entry_point_is_never_offered_again(mode, backend, adc):
 def test_search_loop_state_does_not_grow_with_n():
     """At 1 M rows and nq 1,024 (shapes only, nothing allocated) the loop
     body has no scatter, and no array but the chunk table follows N."""
-    from repro.configs.aisaq_indices import SIFT1M
+    from repro.core.index_config import SIFT1M
     from repro.core.chunk_layout import layout_for
     n, nq = 1_000_000, 1024
     lay = layout_for(SIFT1M, "aisaq")

@@ -217,8 +217,9 @@ print('moe ep OK', err, gerr)
 
 
 def test_ann_cell_runs_small_mesh():
-    """Execute (not just compile) the dry-run ANN search program shape on
-    8 devices with a real small index."""
+    """Execute the mode-B sharded ANN search (index shards over every mesh
+    axis, queries replicated and chunked) on 8 devices with a real small
+    index."""
     run_py("""
 import numpy as np, jax, jax.numpy as jnp
 from repro.data.vectors import make_clustered, make_queries

@@ -9,7 +9,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.configs.base import IndexConfig
+from repro.core.index_config import IndexConfig
 from repro.core import pq
 from repro.core.build import build_index
 from repro.core.dynamic import DynamicHostIndex, DynamicIndexError

@@ -265,7 +265,7 @@ def test_write_index_overwrite_is_atomic(faulty_fixture, tmp_path):
 def test_dynamic_mutation_keeps_crc_coherent(tmp_path):
     """In-place writes + appends re-anchor the sidecar: reload after flush
     verifies every block, and searches on the mutated index pass CRC."""
-    from repro.configs.base import IndexConfig
+    from repro.core.index_config import IndexConfig
     from repro.core.build import build_index
     from repro.core.dynamic import DynamicHostIndex
     from repro.data.vectors import make_clustered

@@ -137,3 +137,76 @@ def test_every_core_module_imports_cleanly():
     pkg_dir = os.path.dirname(core_pkg.__file__)
     for m in pkgutil.iter_modules([pkg_dir]):
         importlib.import_module(f"repro.core.{m.name}")
+
+
+# the seed's LM/GNN/recsys training stack: the retriever imports none of it
+TRAINING_STACK = ("repro.models", "repro.distributed", "repro.optim",
+                  "repro.checkpoint", "repro.configs", "repro.launch.train",
+                  "repro.data.pipeline")
+
+
+def _in_training_stack(mod):
+    return any(mod == t or mod.startswith(t + ".") for t in TRAINING_STACK)
+
+
+def _src_modules():
+    """(dotted name, path) of every module under src/repro."""
+    import repro.core as core_pkg
+    src = os.path.dirname(os.path.dirname(os.path.dirname(core_pkg.__file__)))
+    for dirpath, _, files in os.walk(os.path.join(src, "repro")):
+        for f in sorted(files):
+            if f.endswith(".py"):
+                path = os.path.join(dirpath, f)
+                parts = os.path.relpath(path, src)[:-3].split(os.sep)
+                if parts[-1] == "__init__":
+                    parts = parts[:-1]
+                yield ".".join(parts), path
+
+
+def test_retriever_imports_nothing_of_the_training_stack():
+    """No module outside the training stack imports one inside it, at any
+    nesting (imports inside functions too), by `import a.b` or by
+    `from a import b`."""
+    mods = dict(_src_modules())
+    assert "repro.core.device_index" in mods and "repro.models.moe" in mods
+    bad = []
+    for name, path in sorted(mods.items()):
+        if _in_training_stack(name):
+            continue
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                targets = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0 \
+                    and node.module:
+                # `from repro import configs` names the module in `a.name`
+                targets = [f"{node.module}.{a.name}" for a in node.names]
+            else:
+                continue
+            bad += [f"{name}:{node.lineno} imports {t}" for t in targets
+                    if _in_training_stack(t)]
+    assert not bad, "\n".join(bad)
+
+
+def test_retriever_import_loads_no_training_module():
+    """A fresh interpreter that imports the retriever's entry modules holds
+    no training-stack module afterwards: package `__init__` side effects
+    count too."""
+    import subprocess
+    import sys
+    import repro.core as core_pkg
+    src = os.path.dirname(os.path.dirname(os.path.dirname(core_pkg.__file__)))
+    script = (
+        "import sys\n"
+        "import repro.core.device_index, repro.serving.engine, "
+        "repro.core.build, repro.launch.build_index, "
+        "repro.core.index_config\n"
+        f"stack = {TRAINING_STACK!r}\n"
+        "print('\\n'.join(m for m in sorted(sys.modules) if any("
+        "m == t or m.startswith(t + '.') for t in stack)))\n")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", script], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == "", r.stdout

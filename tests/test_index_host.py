@@ -55,7 +55,7 @@ def test_recall_improves_with_L(index_dirs, small_corpus):
 
 def test_index_switch_shared_centroids(tmp_path, small_corpus, pq_artifacts):
     """Table 4: switching with shared PQ centroids skips the centroid load."""
-    from repro.configs.base import IndexConfig
+    from repro.core.index_config import IndexConfig
     from repro.core.build import build_index
     base, q, _ = small_corpus
     cents, _ = pq_artifacts

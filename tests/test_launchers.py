@@ -1,4 +1,4 @@
-"""CLI smoke tests for the launchers (build_index / serve / dryrun list)."""
+"""CLI smoke tests for the launchers (build_index / serve / train)."""
 import os
 import subprocess
 import sys

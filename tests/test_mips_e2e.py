@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from repro.configs.base import IndexConfig
+from repro.core.index_config import IndexConfig
 from repro.core import pq
 from repro.core.build import build_index
 from repro.core.index_io import HostIndex, recall_at

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.aisaq_indices import KILT_E5_22M, SIFT1B, SIFT1M
+from repro.core.index_config import KILT_E5_22M, SIFT1B, SIFT1M
 from repro.core.chunk_layout import layout_for
 from repro.core.device_index import DeviceIndex, beam_search_device
 from repro.kernels import ops
